@@ -66,7 +66,7 @@ async_fl_smoke:
 	$(PYTHON) -c "import benchmarks.async_fl_bench as a; a.async_fl_smoke()"
 
 # Kernel-diff smoke alone: every fused Pallas kernel (SDP subspace
-# projection, rank-k clip, top-k/int8 delta compression, one-hot
+# projection, rank-k clip, top-k/int8 delta compression, Eq. 2
 # bottleneck evaluation) vs its jnp oracle in interpret mode, plus a
 # tiny seeded solve_sdp with kernel_backend on vs off asserting the
 # identical iteration trajectory.

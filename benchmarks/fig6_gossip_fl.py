@@ -226,10 +226,11 @@ def _sharded_instance(n_users: int, seed: int = 0):
     return tg, data.split(n_users, rng)
 
 
-def _sharded_trainer(
+def sharded_trainer(
     n_users: int, backend: str, *, num_shards: int | None = None,
     seed: int = 0,
 ) -> GossipTrainer:
+    """The sharded benchmark's trainer: cluster topology, 8x8 MLP."""
     c = SHARDED_BENCH_CONFIG
     tg, shards = _sharded_instance(n_users, seed)
     cfg = GossipConfig(
@@ -271,7 +272,7 @@ def sharded_sweep(
         row: dict = {"n_users": n, "meshes": {}}
         losses_by_mesh: dict[int, list[float]] = {}
         for s in meshes:
-            tr = _sharded_trainer(n, "sharded", num_shards=s)
+            tr = sharded_trainer(n, "sharded", num_shards=s)
             losses = [tr.step_round()["mean_loss"]]     # warmup: compile
             t0 = time.perf_counter()
             for _ in range(rounds):
@@ -298,7 +299,7 @@ def sharded_sweep(
         row["losses"] = {str(s): losses_by_mesh[s] for s in meshes}
         row["mesh_loss_max_spread"] = max(spreads) if spreads else 0.0
         if n <= stacked_anchor_max:
-            tr = _sharded_trainer(n, "stacked")
+            tr = sharded_trainer(n, "stacked")
             ref = [tr.step_round()["mean_loss"] for _ in range(rounds + 1)]
             del tr
             row["stacked_losses"] = ref
@@ -349,8 +350,8 @@ def sharded_smoke() -> None:
         f"XLA_FLAGS=--xla_force_host_platform_device_count=2"
     )
     n = 24
-    a = _sharded_trainer(n, "stacked")
-    b = _sharded_trainer(n, "sharded", num_shards=2)
+    a = sharded_trainer(n, "stacked")
+    b = sharded_trainer(n, "sharded", num_shards=2)
     diffs = []
     for _ in range(3):
         ia, ib = a.step_round(), b.step_round()

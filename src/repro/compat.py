@@ -1,68 +1,20 @@
-"""Version compatibility shims for JAX APIs used across the repo.
+"""Dependency gate for the control-plane code.
 
-``shard_map`` graduated from ``jax.experimental.shard_map`` to the top-level
-``jax`` namespace, and its replication-check kwarg was renamed
-``check_rep`` -> ``check_vma`` along the way.  Call sites in this repo use
-the modern spelling (``from repro.compat import shard_map`` with
-``check_vma=...``); this module translates for whichever JAX is installed.
-
-It also hosts the dependency gates the control-plane code uses to degrade
-gracefully when JAX is absent (``jax_available``) and a ``segment_sum``
-re-export: the device-resident SDP solver builds its CSR matvecs on it, and
-``jax.ops.segment_sum`` has moved namespaces before, so the import is
-funneled through here with a scatter-add fallback.
+The SDP solver backends and the fused rounding path gate their device
+paths on ``jax_available`` instead of importing JAX eagerly, so the numpy
+float64 reference paths keep working in a JAX-less environment.
 """
 
 from __future__ import annotations
 
 import functools
-import inspect
-
-try:  # modern JAX
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-except ImportError:  # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_SHARD_MAP_PARAMS = frozenset(inspect.signature(_shard_map).parameters)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, **kwargs):
-    """``jax.shard_map`` with the replication-check kwarg auto-translated."""
-    if "check_vma" in kwargs and "check_vma" not in _SHARD_MAP_PARAMS:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    elif "check_rep" in kwargs and "check_rep" not in _SHARD_MAP_PARAMS:
-        kwargs["check_vma"] = kwargs.pop("check_rep")
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
 
 
 @functools.lru_cache(maxsize=1)
 def jax_available() -> bool:
-    """True when JAX imports cleanly.
-
-    Control-plane code (the SDP solver backends, the fused rounding path)
-    gates its device paths on this instead of importing eagerly, so the
-    numpy float64 reference paths keep working in a JAX-less environment.
-    """
+    """True when JAX imports cleanly."""
     try:
         import jax  # noqa: F401
     except Exception:
         return False
     return True
-
-
-def segment_sum(data, segment_ids, num_segments):
-    """``jax.ops.segment_sum`` for whichever JAX is installed.
-
-    Falls back to an explicit scatter-add when ``jax.ops`` no longer ships
-    the helper (it has migrated namespaces before); both spellings lower to
-    the same scatter-add HLO.
-    """
-    import jax
-
-    seg = getattr(getattr(jax, "ops", None), "segment_sum", None)
-    if seg is not None:
-        return seg(data, segment_ids, num_segments=num_segments)
-    import jax.numpy as jnp
-
-    out = jnp.zeros((num_segments,) + data.shape[1:], dtype=data.dtype)
-    return out.at[segment_ids].add(data)

@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 
 import numpy as np
 
@@ -48,6 +49,9 @@ class RoundingResult:
     expected_bottleneck: float      # Eq. (22)-(23)
     lower_bound: float              # Eq. (24)  (<= OPT)
     upper_bound: float              # Eq. (27)  (>= OPT, see note in DESIGN.md)
+    # Eq. 2 evaluator that scored the samples: "numpy" (host float64),
+    # "jnp" (vmapped gathers) or "pallas" (kernels/bottleneck.py)
+    evaluator: str = "numpy"
 
 
 def _covariance_root(Y: np.ndarray) -> np.ndarray:
@@ -111,7 +115,9 @@ def randomized_rounding(
 ) -> RoundingResult:
     rng = rng or np.random.default_rng(0)
 
+    evaluator = "numpy"
     if backend == "jax":
+        evaluator = _rounding_kernel_backend(kernel_backend)
         assignment, bottleneck, num_feasible = _rounding_fused_jax(
             task_graph,
             compute_graph,
@@ -122,7 +128,7 @@ def randomized_rounding(
             rng,
             strict,
             Y_device=Y_device,
-            kernel_backend=kernel_backend,
+            kernel_backend=evaluator,
         )
     else:
         signs, z = _sample_signs(Y, num_samples, rng)
@@ -154,6 +160,7 @@ def randomized_rounding(
         expected_bottleneck=exp_b,
         lower_bound=lb,
         upper_bound=ub,
+        evaluator=evaluator,
     )
 
 
@@ -239,6 +246,8 @@ def _device_analysis_fn(bqp: FactoredBQP):
     import jax
     import jax.numpy as jnp
 
+    from repro.core.sdp import DOT_PRECISION
+
     key = (
         bqp.p.tobytes(),
         bqp.d.tobytes(),
@@ -260,18 +269,19 @@ def _device_analysis_fn(bqp: FactoredBQP):
     Ct1 = jnp.asarray(bqp._Ct1, jnp.float32)
     P = jnp.float32(bqp._P)
     corner = jnp.float32(bqp.corner)
+    einsum = functools.partial(jnp.einsum, precision=DOT_PRECISION)
 
     def inner(F):
         """Device twin of ``FactoredBQP.inner`` (same closed forms)."""
         F = 0.5 * (F + F.T)
         Fxx = F[:n, :n].reshape(K, T, K, T)
         f = F[:n, -1].reshape(K, T)
-        comp = jnp.einsum("k,t,ktks->s", d, p, Fxx)
+        comp = einsum("k,t,ktks->s", d, p, Fxx)
         blocks = Fxx.transpose(1, 3, 0, 2)[src, dst]      # (|E|, K, K)
-        comm = jnp.einsum("ekl,kl->e", blocks, C)
-        base = jnp.einsum("k,t,kt->", d, p, f)
-        u_i = (C1 + P * d) @ f
-        u_j = Ct1 @ f
+        comm = einsum("ekl,kl->e", blocks, C)
+        base = einsum("k,t,kt->", d, p, f)
+        u_i = jnp.dot(C1 + P * d, f, precision=DOT_PRECISION)
+        u_j = jnp.dot(Ct1, f, precision=DOT_PRECISION)
         q1f = 0.5 * (base + u_i[src] + u_j[dst])
         return comp[src] + comm + 2.0 * q1f + corner * F[-1, -1]
 
@@ -326,9 +336,9 @@ def _rounding_kernel_backend(kernel_backend: str) -> str:
             "choose from ('auto', 'jnp', 'pallas')"
         )
     if kernel_backend == "auto":
-        import jax
+        from repro.kernels.ops import interpret_mode
 
-        return "pallas" if jax.default_backend() == "tpu" else "jnp"
+        return "jnp" if interpret_mode() else "pallas"
     return kernel_backend
 
 
@@ -365,29 +375,20 @@ def _fused_rounding_fn(
     else:
         src = dst = jnp.zeros((0,), dtype=jnp.int32)
 
-    def bottleneck_one(a):
-        onehot = jax.nn.one_hot(a, n_machines, dtype=jnp.float32)  # (T, K)
-        loads = onehot.T @ p                                        # (K,)
-        t_comp = (loads / e)[a]                                     # (T,)
-        delays = C[a[src], a[dst]]                                  # (|E|,)
-        comm = jnp.zeros_like(t_comp).at[src].max(delays)
-        return jnp.max(t_comp + comm)
-
     if kernel_backend == "pallas":
         from repro.kernels.bottleneck import bottleneck_eval_fwd
+        from repro.kernels.ops import interpret_mode
 
-        interp = jax.default_backend() != "tpu"
-        src_oh = jax.nn.one_hot(src, n_tasks, dtype=jnp.float32)   # (|E|, T)
-        dst_oh = jax.nn.one_hot(dst, n_tasks, dtype=jnp.float32)
-
-        def eval_times(assignments):
-            oh = jax.nn.one_hot(assignments, n_machines, dtype=jnp.float32)
-            return bottleneck_eval_fwd(
-                oh, p, e, C, src_oh, dst_oh, interpret=interp
-            )
+        eval_times = functools.partial(
+            bottleneck_eval_fwd, p=p, e=e, C=C, src=src, dst=dst,
+            interpret=interpret_mode(),
+        )
     else:
-        def eval_times(assignments):
-            return jax.vmap(bottleneck_one)(assignments)
+        from repro.kernels.ref import bottleneck_eval_ref
+
+        eval_times = functools.partial(
+            bottleneck_eval_ref, p=p, e=e, C=C, src=src, dst=dst
+        )
 
     @jax.jit
     def rounding(root, g):
@@ -438,33 +439,15 @@ def _fused_rounding_batch_fn(
 
     if kernel_backend == "pallas":
         from repro.kernels.bottleneck import bottleneck_eval_fwd
+        from repro.kernels.ops import interpret_mode
 
-        interp = jax.default_backend() != "tpu"
+        evaluator = functools.partial(
+            bottleneck_eval_fwd, interpret=interpret_mode()
+        )
+    else:
+        from repro.kernels.ref import bottleneck_eval_ref as evaluator
 
     def round_one(p, e, C, src, dst, root, g):
-        def bottleneck_one(a):
-            onehot = jax.nn.one_hot(a, n_machines, dtype=jnp.float32)
-            loads = onehot.T @ p
-            t_comp = (loads / e)[a]
-            delays = C[a[src], a[dst]]
-            comm = jnp.zeros_like(t_comp).at[src].max(delays)
-            return jnp.max(t_comp + comm)
-
-        if kernel_backend == "pallas":
-            src_oh = jax.nn.one_hot(src, n_tasks, dtype=jnp.float32)
-            dst_oh = jax.nn.one_hot(dst, n_tasks, dtype=jnp.float32)
-
-            def eval_times(assignments):
-                oh = jax.nn.one_hot(
-                    assignments, n_machines, dtype=jnp.float32
-                )
-                return bottleneck_eval_fwd(
-                    oh, p, e, C, src_oh, dst_oh, interpret=interp
-                )
-        else:
-            def eval_times(assignments):
-                return jax.vmap(bottleneck_one)(assignments)
-
         S = g.shape[0]
         z = g @ root.T                                  # (S, n+1)
         s = jnp.where(z >= 0, 1.0, -1.0)                # sign with 0 -> +1
@@ -476,7 +459,7 @@ def _fused_rounding_batch_fn(
         strict_mask = any_sel.all(axis=1)               # (S,)
         choice = jnp.where(any_sel[:, None, :], masked, zx)
         assignments = jnp.argmax(choice, axis=1)        # (S, T)
-        times = eval_times(assignments)                 # (S,)
+        times = evaluator(assignments, p=p, e=e, C=C, src=src, dst=dst)
         if strict:
             times = jnp.where(
                 strict_mask.any(),
@@ -659,9 +642,8 @@ def randomized_rounding_batch(
         ]
     )
 
-    fn = _fused_rounding_batch_fn(
-        B, T, K, n_e, strict, _rounding_kernel_backend(kernel_backend)
-    )
+    evaluator = _rounding_kernel_backend(kernel_backend)
+    fn = _fused_rounding_batch_fn(B, T, K, n_e, strict, evaluator)
     assignments, times, feas = fn(p_s, e_s, C_s, src_s, dst_s, roots, g)
 
     out = []
@@ -676,6 +658,7 @@ def randomized_rounding_batch(
                 expected_bottleneck=exp_b,
                 lower_bound=lb,
                 upper_bound=ub,
+                evaluator=evaluator,
             )
         )
     return out

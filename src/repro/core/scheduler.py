@@ -192,7 +192,12 @@ class Schedule:
         separately as ``rounding_lower_bound`` and never overwrites it;
       - ``expected_bottleneck`` (Eqs. 22–23), ``upper_bound`` (Eq. 27),
         ``rounding_lower_bound`` (Eq. 24 re-evaluated at rounding),
-        ``num_feasible``, ``warm_started`` — rounding diagnostics.
+        ``num_feasible``, ``warm_started`` — rounding diagnostics;
+      - ``rounding_evaluator`` — the Eq. 2 evaluator that scored the
+        samples ("numpy", "jnp" or "pallas") and ``rounding_bottleneck``
+        its score of the chosen sample (f32 on device; ``bottleneck`` is
+        the host float64 evaluation of the same assignment); the solver's
+        resolved cone-step kernel is ``solver_stats["kernel_backend"]``.
     """
 
     assignment: np.ndarray
@@ -308,6 +313,8 @@ def schedule(
                 expected_bottleneck=res.expected_bottleneck,
                 upper_bound=res.upper_bound,
                 rounding_lower_bound=res.lower_bound,
+                rounding_evaluator=res.evaluator,
+                rounding_bottleneck=res.bottleneck,
             )
             assignment = res.assignment
             if method == "sdp_ls":

@@ -66,6 +66,14 @@ import numpy as np
 from repro import compat
 from repro.core.bqp import BQPData, FactoredBQP
 
+# Precision of every dot the device solver issues.  On TPU the default f32
+# dot is ONE bf16 pass (relative error ~2e-3, in XLA and in Mosaic kernels
+# alike): measured on a v5e, the DR loop then stops after 225 of the 1025
+# iterations the float64 reference needs at n1 = 1025, with a meaningless
+# bound, and on a 49-dim instance never reaches tol 1e-4 at all.  HIGHEST
+# reproduces the float64 iteration count (PERF.md, Findings).
+DOT_PRECISION = "highest"
+
 
 @dataclasses.dataclass(frozen=True)
 class SDPOptions:
@@ -109,8 +117,8 @@ class SDPOptions:
     # ``kernels.sdp_proj`` (fused matvec + Rayleigh-Ritz Gram + ‖Y‖², and a
     # fused rank-k clip update) — the memory-bound win recorded by
     # ``roofline.py::sdp_batch_profile``.  "jnp" keeps the plain-XLA cone
-    # projection; "auto" picks pallas on TPU and jnp elsewhere (on CPU the
-    # kernels run in interpret mode — exact but slow, tests only).
+    # projection; "auto" picks pallas on an accelerator and jnp on CPU (on
+    # CPU the kernels run in interpret mode — exact but slow, tests only).
     kernel_backend: str = "auto"
 
 
@@ -498,9 +506,10 @@ def _make_device_ops(kind: str, operands, n1: int, n_tasks: int, n_machines: int
     or the structural Kronecker-factor form ("factored").
     """
     import jax.numpy as jnp
+    from jax.ops import segment_sum
 
-    from repro.compat import segment_sum
-
+    einsum = functools.partial(jnp.einsum, precision=DOT_PRECISION)
+    dot = functools.partial(jnp.dot, precision=DOT_PRECISION)
     idx_t = n1 * n1
 
     if kind == "csr":
@@ -523,8 +532,8 @@ def _make_device_ops(kind: str, operands, n1: int, n_tasks: int, n_machines: int
     T, K = n_tasks, n_machines
     n = T * K
     n_e = src.shape[0]
-    C1 = C @ jnp.ones(K, C.dtype)
-    Ct1 = C.T @ jnp.ones(K, C.dtype)
+    C1 = jnp.sum(C, axis=1)
+    Ct1 = jnp.sum(C, axis=0)
     P = jnp.sum(p)
     corner = jnp.sum(d) * P + jnp.sum(C)
     dp = jnp.outer(d, p)                       # (K, T) grid of d⊗p
@@ -543,12 +552,12 @@ def _make_device_ops(kind: str, operands, n1: int, n_tasks: int, n_machines: int
         # <Q̃_e, sym(F)> — same contraction as FactoredBQP.inner
         Fxx = Fs[:n, :n].reshape(K, T, K, T)
         f = Fs[:n, n].reshape(K, T)
-        comp = jnp.einsum("k,t,ktks->s", d, p, Fxx)
+        comp = einsum("k,t,ktks->s", d, p, Fxx)
         blocks = Fxx.transpose(1, 3, 0, 2)[src, dst]       # (|E|, K, K)
-        comm = jnp.einsum("ekl,kl->e", blocks, C)
-        base = jnp.einsum("k,t,kt->", d, p, f)
-        u_i = (C1 + P * d) @ f
-        u_j = Ct1 @ f
+        comm = einsum("ekl,kl->e", blocks, C)
+        base = einsum("k,t,kt->", d, p, f)
+        u_i = dot(C1 + P * d, f)
+        u_j = dot(Ct1, f)
         q1f = 0.5 * (base + u_i[src] + u_j[dst])
         inner = comp[src] + comm + 2.0 * q1f + corner * Fs[n, n]
         r_q = inner / qs - 4.0 * v[idx_t] + v[idx_t + 1 :]
@@ -566,8 +575,8 @@ def _make_device_ops(kind: str, operands, n1: int, n_tasks: int, n_machines: int
         W2 = W2.reshape(T, T)
         # X-X block: Σ_e y_e · sym(D ⊗ (p δ_iᵀ) + C ⊗ (δ_i δ_jᵀ))
         M = 0.5 * (jnp.outer(p, c_i) + jnp.outer(c_i, p))
-        Z = jnp.einsum("kl,k,ts->ktls", eyeK, d, M)
-        T1 = jnp.einsum("kl,ts->ktls", C, W2)
+        Z = einsum("kl,k,ts->ktls", eyeK, d, M)
+        T1 = einsum("kl,ts->ktls", C, W2)
         Z = Z + 0.5 * (T1 + T1.transpose(2, 3, 0, 1))
         # borders: Σ_e y_e q1_e + the A-row borders (0.5 per machine)
         g = 0.5 * (
@@ -615,19 +624,21 @@ def _cone_fns(k: int, eig_iters: int, kernel_backend: str = "jnp"):
     import jax.numpy as jnp
     from jax import lax
 
+    mm = functools.partial(jnp.matmul, precision=DOT_PRECISION)
+
     def cone_full(Y):
         ew, EV = jnp.linalg.eigh(Y)
-        Yp = (EV * jnp.maximum(ew, 0.0)) @ EV.T
+        Yp = mm(EV * jnp.maximum(ew, 0.0), EV.T)
         return Yp, EV[:, :k]          # basis <- k most-negative eigvecs
 
     def _epilogue(Y, V, YV, G, sigma, eig_tol, clip_update):
         theta, U = jnp.linalg.eigh(G)            # Ritz values, ascending
-        W = V @ U
+        W = mm(V, U)
         neg = theta < 0.0
         # Ritz residual of the negative pairs: ‖Y w - θ w‖ certifies the
         # clip; saturation (num_neg == k) means negatives may extend
         # beyond the tracked subspace — both force the full-eigh path.
-        R = YV @ U - W * theta
+        R = mm(YV, U) - W * theta
         res = jnp.sqrt(jnp.sum(jnp.where(neg, jnp.sum(R * R, axis=0), 0.0)))
         ok = (jnp.sum(neg) < k) & (res <= eig_tol * jnp.maximum(sigma, 1.0))
         Yp = clip_update(Y, W, jnp.where(neg, theta, 0.0))
@@ -641,22 +652,21 @@ def _cone_fns(k: int, eig_iters: int, kernel_backend: str = "jnp"):
         sigma = jnp.linalg.norm(Y)
 
         def sweep(_, Vc):
-            Q, _ = jnp.linalg.qr(sigma * Vc - Y @ Vc)
+            Q, _ = jnp.linalg.qr(sigma * Vc - mm(Y, Vc))
             return Q
 
         V = lax.fori_loop(0, eig_iters, sweep, V)
-        YV = Y @ V
+        YV = mm(Y, V)
         return _epilogue(
-            Y, V, YV, V.T @ YV, sigma, eig_tol,
-            lambda Y, W, th: Y - (W * th) @ W.T,
+            Y, V, YV, mm(V.T, YV), sigma, eig_tol,
+            lambda Y, W, th: Y - mm(W * th, W.T),
         )
 
     def cone_partial_pallas(Y, V, eig_tol):
-        import jax
-
+        from repro.kernels.ops import interpret_mode
         from repro.kernels.sdp_proj import rank_k_update_fwd, sdp_subspace_fwd
 
-        interp = jax.default_backend() != "tpu"
+        interp = interpret_mode()
         YV, G, ss = sdp_subspace_fwd(Y, V, interpret=interp)
         sigma = jnp.sqrt(ss)
 
@@ -1011,9 +1021,10 @@ def _solve_jax(bqp, opts: SDPOptions, proj: _AffineProjector, warm_start: dict |
     if V_np is None or np.asarray(V_np).shape != (n1, k):
         V_np = np.eye(n1, k)   # placeholder; iteration 0 full-eigh reseeds it
 
+    kernel_backend = _resolve_kernel_backend(opts)
     run = _dr_jax_fn(
         n1, opts.check_every, k, opts.eig_iters, opts.eig_refresh, kind, n_t,
-        n_k, _resolve_kernel_backend(opts)
+        n_k, kernel_backend
     )
     w, V, v_cone, it, residual, n_full, n_partial = run(
         jnp.asarray(w_np, dtype),
@@ -1031,6 +1042,7 @@ def _solve_jax(bqp, opts: SDPOptions, proj: _AffineProjector, warm_start: dict |
     stats = {
         "solver_backend": "jax",
         "solver_dtype": "float32",
+        "kernel_backend": kernel_backend,
         "constraint_kind": kind,
         "warm_started": warm,
         "eig_full": int(n_full),
@@ -1092,9 +1104,10 @@ def _solve_jax_batch(bqps, opts: SDPOptions, projs, warm_starts):
         w_stack.append(np.asarray(w_np, np.float32))
         V_stack.append(np.asarray(V_np, np.float32))
 
+    kernel_backend = _resolve_kernel_backend(opts)
     run = _dr_jax_batch_fn(
         n1, opts.check_every, k, opts.eig_iters, opts.eig_refresh, kind, n_t,
-        n_k, _resolve_kernel_backend(opts)
+        n_k, kernel_backend
     )
     _BATCH_RUN_CALLS += 1
     w, V, v_cone, it, res, done, it_conv, n_full, n_partial = run(
@@ -1116,6 +1129,7 @@ def _solve_jax_batch(bqps, opts: SDPOptions, projs, warm_starts):
         stats = {
             "solver_backend": "jax",
             "solver_dtype": "float32",
+            "kernel_backend": kernel_backend,
             "constraint_kind": kind,
             "warm_started": warm_flags[i],
             "eig_full": int(n_full[i]),
@@ -1166,10 +1180,10 @@ def _resolve_backend(opts: SDPOptions, n1: int) -> str:
 def _resolve_kernel_backend(opts: SDPOptions) -> str:
     """Pick the cone-projection kernel lane for the jax backend.
 
-    "auto" = the fused Pallas kernels on TPU, plain XLA elsewhere (in
-    interpret mode the kernels are exact but orders of magnitude slower, so
-    CPU only runs them when asked explicitly — tests and the differential
-    harness do).
+    "auto" = the fused Pallas kernels on an accelerator, plain XLA on CPU
+    (in interpret mode the kernels are exact but orders of magnitude
+    slower, so CPU only runs them when asked explicitly — tests and the
+    differential harness do).
     """
     kb = opts.kernel_backend
     if kb not in ("auto", "jnp", "pallas"):
@@ -1178,9 +1192,9 @@ def _resolve_kernel_backend(opts: SDPOptions) -> str:
             "choose from ('auto', 'jnp', 'pallas')"
         )
     if kb == "auto":
-        import jax
+        from repro.kernels.ops import interpret_mode
 
-        return "pallas" if jax.default_backend() == "tpu" else "jnp"
+        return "jnp" if interpret_mode() else "pallas"
     return kb
 
 

@@ -55,7 +55,13 @@ import numpy as np
 
 from repro.core.graphs import TaskGraph
 from repro.data.synthetic import ImageDataset, stack_shards
-from repro.kernels.gossip_mix import gossip_mix_all_fwd, gossip_mix_block_fwd
+from repro.kernels.compress import compress_block_len
+from repro.kernels.gossip_mix import (
+    gossip_mix_all_fwd,
+    gossip_mix_block_fwd,
+    mix_block_len,
+)
+from repro.kernels.ops import interpret_mode
 from repro.kernels.ref import gossip_mix_segment_ref
 from repro.train.optim import SGDM
 
@@ -78,13 +84,35 @@ class GossipConfig:
     # XLA_FLAGS=--xla_force_host_platform_device_count=N before jax loads.
     num_shards: int | None = None
     mix_backend: str = "auto"     # stacked exchange: "segment_sum" | "pallas"
-    mix_block_len: int = 65536    # L-block of the all-receivers Pallas kernel
     # Stacked delta-compression stage: "pallas" fuses the top-k/int8
     # quantization with the error-feedback residual into one stream of the
     # stacked delta (kernels/compress.py, DESIGN.md §12); "jnp" keeps the
     # vmapped roundtrip + subtract.  "auto" = jnp on CPU, pallas on
     # accelerators (mirrors mix_backend).
     compress_backend: str = "auto"
+
+
+def _mix_leaves(msgs, rows: int, mix, halo_rows: int = 0):
+    """Run a Pallas mix over every leaf of ``msgs`` in one pass.
+
+    The leaves are flattened to (rows, ·) and concatenated into one slab,
+    padded to a whole number of lane blocks sized by ``mix_block_len``
+    (``halo_rows`` counts the second sender slab the sharded mix streams);
+    ``mix(X, block_len)`` returns the (rows, padded L) mix, which is split
+    back into the leaf shapes.
+    """
+    leaves, treedef = jax.tree.flatten(msgs)
+    flats = [l.reshape(rows, -1) for l in leaves]
+    X = jnp.concatenate(flats, axis=1)
+    L = X.shape[1]
+    bl = mix_block_len(L, rows, rows, halo_rows)
+    X = jnp.pad(X, ((0, 0), (0, (-L) % bl)))
+    out = mix(X, bl)
+    offs = np.cumsum([0] + [f.shape[1] for f in flats])
+    return treedef.unflatten([
+        out[:, offs[k]: offs[k + 1]].reshape(l.shape).astype(l.dtype)
+        for k, l in enumerate(leaves)
+    ])
 
 
 def mixing_arrays(
@@ -284,7 +312,7 @@ class GossipTrainer:
         if mix_backend == "auto":
             # The Pallas kernel wins on accelerators; on CPU it would run in
             # interpret mode, so the segment_sum path is the fast default.
-            return "segment_sum" if jax.default_backend() == "cpu" else "pallas"
+            return "segment_sum" if interpret_mode() else "pallas"
         return mix_backend
 
     @staticmethod
@@ -297,7 +325,7 @@ class GossipTrainer:
         if compress_backend == "auto":
             # Same trade-off as the mix: interpret mode on CPU is exact but
             # slow, so the fused kernel is opt-in off-accelerator.
-            return "jnp" if jax.default_backend() == "cpu" else "pallas"
+            return "jnp" if interpret_mode() else "pallas"
         return compress_backend
 
     def _make_compress_stage(self):
@@ -329,7 +357,7 @@ class GossipTrainer:
 
         from repro.kernels.compress import int8_roundtrip_fwd, topk_mask_fwd
 
-        interpret = jax.default_backend() == "cpu"
+        interpret = interpret_mode()
         is_topk = isinstance(comp, TopK)
 
         def one_leaf(x):
@@ -338,9 +366,7 @@ class GossipTrainer:
             rows = x.shape[0]
             flat = x.reshape(rows, -1)
             L = flat.shape[1]
-            # Same on-chip budget as the mix kernel: (rows, bl) in + two
-            # (rows, bl) out blocks stay a few MB regardless of user count.
-            bl = min(65536, max(1024, (1 << 20) // rows), L)
+            bl = compress_block_len(rows, L)
             if is_topk:
                 kk = max(1, int(comp.fraction * L))
                 vals, _ = jax.lax.top_k(jnp.abs(flat), kk)
@@ -528,7 +554,7 @@ class GossipTrainer:
         w_edge = jnp.asarray(self._w_edge)
         W = None if self._W is None else jnp.asarray(self._W)
         mix_backend = self.mix_backend
-        interpret = jax.default_backend() == "cpu"
+        interpret = interpret_mode()
         local_scan = self._make_local_scan()
         compress_stage = None if comp is None else self._make_compress_stage()
 
@@ -542,28 +568,12 @@ class GossipTrainer:
             return jax.tree.map(seg, msgs)
 
         def mix_pallas(msgs):
-            leaves, treedef = jax.tree.flatten(msgs)
-            flats = [l.reshape(n, -1) for l in leaves]
-            sizes = [f.shape[1] for f in flats]
-            X = jnp.concatenate(flats, axis=1)
-            L = X.shape[1]
-            # Budget the (n, bl) input + (n, bl) output blocks to ~8 MB of
-            # on-chip memory regardless of user count; a fixed 64k block at
-            # N_T=128 would want 64 MB of VMEM/shared memory.
-            bl_cap = max(1024, (1 << 20) // n)
-            bl = min(cfg.mix_block_len, bl_cap, L)
-            pad = (-L) % bl
-            if pad:
-                X = jnp.pad(X, ((0, 0), (0, pad)))
-            out = gossip_mix_all_fwd(X, W, block_len=bl, interpret=interpret)[:, :L]
-            offs = np.cumsum([0] + sizes)
-            parts = [
-                out[:, offs[k] : offs[k + 1]].reshape(leaves[k].shape).astype(
-                    leaves[k].dtype
-                )
-                for k in range(len(leaves))
-            ]
-            return treedef.unflatten(parts)
+            return _mix_leaves(
+                msgs, n,
+                lambda X, bl: gossip_mix_all_fwd(
+                    X, W, block_len=bl, interpret=interpret
+                ),
+            )
 
         mix = mix_segment if mix_backend == "segment_sum" else mix_pallas
 
@@ -765,7 +775,7 @@ class GossipTrainer:
         n = self.n
         comp = cfg.compressor
         mix_backend = self.mix_backend
-        interpret = jax.default_backend() == "cpu"
+        interpret = interpret_mode()
         local_scan = self._make_local_scan()
         compress_stage = None if comp is None else self._make_compress_stage()
         halo_rows = self.halo_stats["halo_rows_per_shard"]
@@ -807,33 +817,18 @@ class GossipTrainer:
                 incoming = jax.tree.map(mix_leaf, msgs)
             else:
                 wb = ec["Wb"][0]
-                leaves, treedef = jax.tree.flatten(msgs)
-                flats = [l.reshape(m, -1) for l in leaves]
-                sizes = [f.shape[1] for f in flats]
-                X = jnp.concatenate(flats, axis=1)
-                L = X.shape[1]
-                # Same on-chip budget as the stacked pallas mix, counting
-                # the halo slab that now streams alongside the local one.
-                bl_cap = max(1024, (1 << 20) // max(m + halo_rows, 1))
-                bl = min(cfg.mix_block_len, bl_cap, L)
-                pad = (-L) % bl
-                if pad:
-                    X = jnp.pad(X, ((0, 0), (0, pad)))
-                if halo_rows:
-                    out = gossip_mix_block_fwd(
-                        X, wb, gather_halo(X), ec["Wh"][0],
-                        block_len=bl, interpret=interpret,
-                    )[:, :L]
-                else:
-                    out = gossip_mix_all_fwd(
+
+                def mix_block(X, bl):
+                    if halo_rows:
+                        return gossip_mix_block_fwd(
+                            X, wb, gather_halo(X), ec["Wh"][0],
+                            block_len=bl, interpret=interpret,
+                        )
+                    return gossip_mix_all_fwd(
                         X, wb, block_len=bl, interpret=interpret
-                    )[:, :L]
-                offs = np.cumsum([0] + sizes)
-                incoming = treedef.unflatten([
-                    out[:, offs[k]: offs[k + 1]]
-                    .reshape(leaves[k].shape).astype(leaves[k].dtype)
-                    for k in range(len(leaves))
-                ])
+                    )
+
+                incoming = _mix_leaves(msgs, m, mix_block, halo_rows)
 
             params = jax.tree.map(
                 lambda p, inc: (

@@ -37,6 +37,14 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.vmem import lane_block
+
+
+def compress_block_len(rows: int, length: int) -> int:
+    """Lane block for a (rows, length) delta: the delta block in and the
+    message and residual blocks out, all under the scoped-VMEM budget."""
+    return lane_block(length, rows, rows, rows)
+
 
 def _topk_kernel(x_ref, t_ref, m_ref, r_ref):
     x = x_ref[...].astype(jnp.float32)          # (N, bl)
@@ -58,7 +66,7 @@ def _int8_kernel(x_ref, s_ref, m_ref, r_ref):
 def _blocked_rowstat_call(kernel, X, row_stat, *, block_len, interpret):
     n, l = X.shape
     assert row_stat.shape == (n,), (row_stat.shape, n)
-    bl = min(block_len, l)
+    bl = min(block_len or compress_block_len(n, l), l)
     pad = (-l) % bl
     if pad:
         X = jnp.pad(X, ((0, 0), (0, pad)))
@@ -87,7 +95,7 @@ def topk_mask_fwd(
     X: jnp.ndarray,        # (N, L) stacked per-user flat deltas
     thresh: jnp.ndarray,   # (N,) per-row keep threshold (k-th largest |x|)
     *,
-    block_len: int = 65536,
+    block_len: int | None = None,
     interpret: bool = False,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """One stream of X -> (sparsified msgs, error-feedback residual)."""
@@ -100,7 +108,7 @@ def int8_roundtrip_fwd(
     X: jnp.ndarray,        # (N, L) stacked per-user flat deltas
     scale: jnp.ndarray,    # (N,) per-row symmetric quantization scale (> 0)
     *,
-    block_len: int = 65536,
+    block_len: int | None = None,
     interpret: bool = False,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """One stream of X -> (dequantized int8 msgs, error-feedback residual)."""

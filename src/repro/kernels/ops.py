@@ -1,8 +1,8 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On TPU these dispatch the compiled kernels; everywhere else they run the
-kernel body in interpret mode (bit-accurate Python execution) so CPU tests
-validate the exact kernel logic.  Set ``REPRO_FORCE_REF=1`` to bypass
+On CPU they run the kernel body in interpret mode (bit-accurate Python
+execution) so tests validate the exact kernel logic; on any accelerator
+they dispatch the compiled kernels.  Set ``REPRO_FORCE_REF=1`` to bypass
 kernels entirely (pure-jnp oracles).
 """
 
@@ -24,8 +24,15 @@ from repro.kernels.rmsnorm import rmsnorm_fwd
 from repro.kernels.sdp_proj import rank_k_update_fwd, sdp_subspace_fwd
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def interpret_mode() -> bool:
+    """The one rule for every Pallas call site: interpret mode on CPU only.
+
+    On an accelerator the kernels compile (a kernel the platform cannot
+    compile fails loudly there rather than silently running interpreted),
+    and every ``auto`` backend switch picks its kernel exactly when this is
+    False.
+    """
+    return jax.default_backend() == "cpu"
 
 
 def _force_ref() -> bool:
@@ -44,7 +51,8 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         out = ref.flash_attention_ref(qt, kt, vt, causal=causal, window=window)
     else:
         out = flash_attention_fwd(
-            qt, kt, vt, causal=causal, window=window, interpret=_interpret()
+            qt, kt, vt, causal=causal, window=window,
+            interpret=interpret_mode(),
         )
     return jnp.swapaxes(out, 1, 2)
 
@@ -55,7 +63,7 @@ def decode_attention(q, k_cache, v_cache, valid_len):
     if _force_ref():
         return ref.decode_attention_ref(q, k_cache, v_cache, valid_len)
     return decode_attention_fwd(
-        q, k_cache, v_cache, valid_len, interpret=_interpret()
+        q, k_cache, v_cache, valid_len, interpret=interpret_mode()
     )
 
 
@@ -67,7 +75,7 @@ def rmsnorm(x, scale):
     if _force_ref():
         out = ref.rmsnorm_ref(x2, scale)
     else:
-        out = rmsnorm_fwd(x2, scale, interpret=_interpret())
+        out = rmsnorm_fwd(x2, scale, interpret=interpret_mode())
     return out.reshape(shape)
 
 
@@ -76,7 +84,7 @@ def gossip_mix(stacked, weights):
     """(N, L) neighbor params + (N,) weights -> (L,) aggregated params."""
     if _force_ref():
         return ref.gossip_mix_ref(stacked, weights)
-    return gossip_mix_fwd(stacked, weights, interpret=_interpret())
+    return gossip_mix_fwd(stacked, weights, interpret=interpret_mode())
 
 
 @jax.jit
@@ -84,7 +92,7 @@ def sdp_subspace(Y, V):
     """(n, n) iterate + (n, k) basis -> (Y@V, VᵀYV, ΣY²) in one Y stream."""
     if _force_ref():
         return ref.sdp_subspace_ref(Y, V)
-    return sdp_subspace_fwd(Y, V, interpret=_interpret())
+    return sdp_subspace_fwd(Y, V, interpret=interpret_mode())
 
 
 @jax.jit
@@ -92,7 +100,7 @@ def rank_k_update(Y, A, B):
     """(n, n) − (n, k) @ (n, k)ᵀ without materializing the outer product."""
     if _force_ref():
         return ref.rank_k_update_ref(Y, A, B)
-    return rank_k_update_fwd(Y, A, B, interpret=_interpret())
+    return rank_k_update_fwd(Y, A, B, interpret=interpret_mode())
 
 
 @jax.jit
@@ -100,7 +108,7 @@ def compress_topk(X, thresh):
     """(N, L) deltas + (N,) thresholds -> (msgs, residual) in one stream."""
     if _force_ref():
         return ref.topk_mask_ref(X, thresh)
-    return topk_mask_fwd(X, thresh, interpret=_interpret())
+    return topk_mask_fwd(X, thresh, interpret=interpret_mode())
 
 
 @jax.jit
@@ -108,14 +116,14 @@ def compress_int8(X, scale):
     """(N, L) deltas + (N,) scales -> (dequantized msgs, residual)."""
     if _force_ref():
         return ref.int8_roundtrip_ref(X, scale)
-    return int8_roundtrip_fwd(X, scale, interpret=_interpret())
+    return int8_roundtrip_fwd(X, scale, interpret=interpret_mode())
 
 
 @jax.jit
-def bottleneck_eval(onehot, p, e, C, src_onehot, dst_onehot):
-    """(S, T, K) one-hot samples -> (S,) Eq. 2 bottleneck times."""
+def bottleneck_eval(assign, p, e, C, src, dst):
+    """(S, T) sampled assignments -> (S,) Eq. 2 bottleneck times."""
     if _force_ref():
-        return ref.bottleneck_eval_ref(onehot, p, e, C, src_onehot, dst_onehot)
+        return ref.bottleneck_eval_ref(assign, p, e, C, src, dst)
     return bottleneck_eval_fwd(
-        onehot, p, e, C, src_onehot, dst_onehot, interpret=_interpret()
+        assign, p, e, C, src, dst, interpret=interpret_mode()
     )

@@ -112,32 +112,34 @@ def int8_roundtrip_ref(
 
 
 def bottleneck_eval_ref(
-    onehot: jnp.ndarray,       # (S, T, K) one-hot assignments
-    p: jnp.ndarray,            # (T,)
-    e: jnp.ndarray,            # (K,)
-    C: jnp.ndarray,            # (K, K)
-    src_onehot: jnp.ndarray,   # (E, T) one-hot edge sources (all-zero = inert)
-    dst_onehot: jnp.ndarray,   # (E, T)
+    assign: jnp.ndarray,   # (S, T) int machine index per task per sample
+    p: jnp.ndarray,        # (T,)
+    e: jnp.ndarray,        # (K,)
+    C: jnp.ndarray,        # (K, K)
+    src: jnp.ndarray,      # (E,) int edge sources (E may be 0)
+    dst: jnp.ndarray,      # (E,) int edge destinations
 ) -> jnp.ndarray:
-    """Eq. 2 over samples as dense one-hot contractions (the kernel contract).
+    """Eq. 2 over samples by index gathers — the kernel contract and the
+    fused rounding's jnp evaluator.
 
-    Semantic equivalence to the index-gather evaluator
-    (``bottleneck_time_batch``) is pinned separately in the property suite.
+    Per sample: machine loads (scatter-add of the workloads), each task's
+    compute time ``(loads / e)[a]``, each edge's delay ``C[a[src], a[dst]]``
+    max-accumulated into its source task from zero, then the max over
+    tasks.  Equivalence to the float64 host evaluator
+    (``bottleneck_time_batch``) is pinned in the property suite.
     """
-    if src_onehot.shape[0] == 0:
-        src_onehot = jnp.zeros((1, onehot.shape[1]), jnp.float32)
-        dst_onehot = jnp.zeros((1, onehot.shape[1]), jnp.float32)
-    A = onehot.astype(jnp.float32)
-    S = src_onehot.astype(jnp.float32)
-    D = dst_onehot.astype(jnp.float32)
-    loads = jnp.einsum("stk,t->sk", A, p.astype(jnp.float32))
-    per_machine = loads / e.astype(jnp.float32)
-    t_comp = jnp.einsum("stk,sk->st", A, per_machine)
-    m_src = jnp.einsum("et,stk->sek", S, A)
-    m_dst = jnp.einsum("et,stk->sek", D, A)
-    delays = jnp.einsum("sek,kl,sel->se", m_src, C.astype(jnp.float32), m_dst)
-    comm = jnp.max(delays[:, :, None] * S[None, :, :], axis=1)
-    return jnp.max(t_comp + comm, axis=1)
+    n_machines = e.shape[0]
+    p = p.astype(jnp.float32)
+    e = e.astype(jnp.float32)
+    C = C.astype(jnp.float32)
+
+    def one(a):
+        loads = jnp.zeros(n_machines, jnp.float32).at[a].add(p)
+        t_comp = (loads / e)[a]
+        comm = jnp.zeros_like(t_comp).at[src].max(C[a[src], a[dst]])
+        return jnp.max(t_comp + comm)
+
+    return jax.vmap(one)(assign)
 
 
 def gossip_mix_segment_ref(

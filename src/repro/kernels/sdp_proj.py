@@ -19,7 +19,8 @@ Two kernels cover the loop:
     stream, so the rank-k outer product is never materialized.
 
 Inputs may be f32 or bf16; all arithmetic is f32 (the solver's working
-precision).  ``sdp_subspace_fwd`` returns f32 (its outputs feed the f32
+precision), and the dots ask for it explicitly: the TPU default is one bf16
+pass.  ``sdp_subspace_fwd`` returns f32 (its outputs feed the f32
 ``eigh``/``qr`` epilogue); ``rank_k_update_fwd`` casts back to ``Y.dtype``.
 Rows are padded to the block size with zeros — zero rows of ``Y``/``V``
 contribute nothing to any of the reductions — and sliced off the outputs.
@@ -39,10 +40,17 @@ def _pad_rows(x: jnp.ndarray, rows: int) -> jnp.ndarray:
     return jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1))
 
 
+def _dot(a, b):
+    # f32 at full precision: the default TPU dot is one bf16 pass, which
+    # stalls the DR solve (core/sdp.py, DOT_PRECISION)
+    return jnp.dot(a, b, precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
+
+
 def _subspace_kernel(y_ref, vfull_ref, vblk_ref, yv_ref, g_ref, ss_ref):
     i = pl.program_id(0)
     y = y_ref[...].astype(jnp.float32)            # (bn, np)
-    yv = y @ vfull_ref[...].astype(jnp.float32)   # (bn, k)
+    yv = _dot(y, vfull_ref[...].astype(jnp.float32))   # (bn, k)
     yv_ref[...] = yv
 
     @pl.when(i == 0)
@@ -50,7 +58,7 @@ def _subspace_kernel(y_ref, vfull_ref, vblk_ref, yv_ref, g_ref, ss_ref):
         g_ref[...] = jnp.zeros_like(g_ref)
         ss_ref[...] = jnp.zeros_like(ss_ref)
 
-    g_ref[...] += vblk_ref[...].astype(jnp.float32).T @ yv
+    g_ref[...] += _dot(vblk_ref[...].astype(jnp.float32).T, yv)
     ss_ref[...] += jnp.sum(y * y)
 
 
@@ -104,7 +112,7 @@ def _rank_k_kernel(y_ref, ablk_ref, bfull_ref, o_ref):
     y = y_ref[...].astype(jnp.float32)            # (bn, np)
     a = ablk_ref[...].astype(jnp.float32)         # (bn, k)
     b = bfull_ref[...].astype(jnp.float32)        # (np, k)
-    o_ref[...] = (y - a @ b.T).astype(o_ref.dtype)
+    o_ref[...] = (y - _dot(a, b.T)).astype(o_ref.dtype)
 
 
 def rank_k_update_fwd(

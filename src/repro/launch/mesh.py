@@ -16,14 +16,23 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     Multi-pod: (pod=2, data=16, model=16) = 512 chips across 2 pods."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_debug_mesh(n_devices: int | None = None, model: int = 2) -> jax.sharding.Mesh:
     """Small mesh over whatever devices exist (tests)."""
     n = n_devices or len(jax.devices())
     model = min(model, n)
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return jax.make_mesh(
+        (n // model, model), ("data", "model"), axis_types=_auto(2)
+    )
+
+
+def _auto(n_axes: int) -> tuple:
+    """Auto axis types: the models place their tensors through sharding
+    constraints (``MeshRules.constrain``), not explicit-axis typing, which
+    ``jax.make_mesh`` defaults to."""
+    return (jax.sharding.AxisType.Auto,) * n_axes
 
 
 def dp_axes(mesh: jax.sharding.Mesh) -> tuple[str, ...]:

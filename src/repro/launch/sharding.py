@@ -7,7 +7,7 @@ population-scale FL engine (``repro.fl.gossip``, DESIGN.md §13) shards the
 stacked ``(N_T, …)`` user-replica pytree across a 1-D ``"users"`` device
 mesh: the leading user axis is split into contiguous equal blocks (one per
 shard, padded with inert users when ``N_T % shards != 0``), everything
-else replicated.  The round body runs under ``repro.compat.shard_map`` and
+else replicated.  The round body runs under ``jax.shard_map`` and
 the mixing matrix becomes block-local work plus a boundary-row halo
 exchange.  On a host-only platform, fake devices stand in for a real mesh:
 set ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` **before the
@@ -80,10 +80,10 @@ class UserMesh:
     def build(cls, num_shards: int | None = None) -> "UserMesh":
         """Mesh over the first ``num_shards`` devices (all by default).
 
-        Raises with a fake-device hint when the host exposes fewer
-        devices than requested — the count must be forced via
-        ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` before the
-        first jax import; it cannot be raised afterwards.
+        Raises when fewer devices are visible than requested.  On the CPU
+        platform the hint names the fake-device flag, which must be set
+        before the first jax import; on an accelerator the shard count is
+        bounded by the chips attached.
         """
         devices = jax.devices()
         if num_shards is None:
@@ -91,12 +91,17 @@ class UserMesh:
         if num_shards < 1:
             raise ValueError(f"need >= 1 shard, got {num_shards}")
         if num_shards > len(devices):
+            platform = devices[0].platform
+            hint = (
+                f"set XLA_FLAGS=--xla_force_host_platform_device_count="
+                f"{num_shards} before the first jax import"
+                if platform == "cpu"
+                else f"run on a host with >= {num_shards} {platform} chips "
+                "or lower num_shards"
+            )
             raise ValueError(
                 f"requested {num_shards} user shards but only "
-                f"{len(devices)} device(s) are visible; on a host-only "
-                f"platform set XLA_FLAGS="
-                f"--xla_force_host_platform_device_count={num_shards} "
-                f"before the first jax import"
+                f"{len(devices)} {platform} device(s) are visible; {hint}"
             )
         return cls(mesh=Mesh(np.asarray(devices[:num_shards]), (USER_AXIS,)))
 
@@ -117,11 +122,9 @@ class UserMesh:
     def shard_map(
         self, fn: Callable, in_specs, out_specs, **kwargs
     ) -> Callable:
-        """``repro.compat.shard_map`` over this mesh (jax-version shim)."""
-        from repro.compat import shard_map
-
+        """``jax.shard_map`` over this mesh."""
         kwargs.setdefault("check_vma", False)
-        return shard_map(
+        return jax.shard_map(
             fn, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
             **kwargs,
         )
@@ -317,7 +320,6 @@ class MeshRules:
     # -- distributed decode attention -------------------------------------
     def sharded_decode_attention(self, q, k_cache, v_cache, valid):
         """q (B,H,hd) replicated over tp; caches seq-sharded over tp."""
-        from repro.compat import shard_map
 
         from repro.models.attention import (
             decode_attention_local,
@@ -331,7 +333,7 @@ class MeshRules:
         dp, tp = self.dp, self.tp_axis
         b = dp if _divisible(q.shape[0], self.dp_size) else None
         fn = functools.partial(decode_attention_seq_sharded, axis_name=tp)
-        return shard_map(
+        return jax.shard_map(
             fn,
             mesh=self.mesh,
             in_specs=(

@@ -232,7 +232,6 @@ def moe_ffn_sharded(
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
 
     dp, tp = rules.dp, rules.tp_axis
     tp_size = rules.tp_size
@@ -255,7 +254,7 @@ def moe_ffn_sharded(
         w_specs = (P(tp, None, None),) * 3
     else:
         w_specs = (P(None, None, tp), P(None, None, tp), P(None, tp, None))
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         inner,
         mesh=rules.mesh,
         in_specs=(P(b_spec, tp, None), P(None, None)) + w_specs,

@@ -78,11 +78,9 @@ def test_decode_seq_sharded_matches_local():
     import functools
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from repro.compat import shard_map
-
     mesh = Mesh(np.array(jax.devices()[:1]).reshape(1), ("model",))
     fn = functools.partial(decode_attention_seq_sharded, axis_name="model")
-    got = shard_map(
+    got = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(None, None, None), P(None, "model", None, None),
                   P(None, "model", None, None), P(None, "model")),

@@ -17,10 +17,13 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
 import json, jax
 import repro.launch.dryrun as dr
 
+AUTO = jax.sharding.AxisType.Auto
+
 def small_mesh(multi_pod=False):
     if multi_pod:
-        return jax.make_mesh((2, 2, 4), ("pod", "data", "model"))
-    return jax.make_mesh((4, 4), ("data", "model"))
+        return jax.make_mesh((2, 2, 4), ("pod", "data", "model"),
+                             axis_types=(AUTO,) * 3)
+    return jax.make_mesh((4, 4), ("data", "model"), axis_types=(AUTO,) * 2)
 
 dr.make_production_mesh = small_mesh
 out = []

@@ -11,7 +11,7 @@ three ways:
      with ``kernel_backend="pallas"`` reproduce the jnp path's iteration
      count and projection decisions exactly and the iterate to f32
      tolerance (mirroring ``tests/test_sdp_batch.py``), and the fused
-     rounding with the one-hot bottleneck kernel returns the identical
+     rounding with the bottleneck kernel returns the identical
      assignment;
   3. randomized-shape property tests live in ``tests/test_property.py``.
 """
@@ -212,19 +212,13 @@ def test_compress_degenerate_zero():
 
 def _bottleneck_args(s, n_t, n_k, n_edges, seed=0):
     r = np.random.default_rng(seed)
-    a = r.integers(0, n_k, size=(s, n_t))
-    oh = jax.nn.one_hot(jnp.asarray(a), n_k, dtype=jnp.float32)
+    a = jnp.asarray(r.integers(0, n_k, size=(s, n_t)), jnp.int32)
     p = jnp.asarray(r.uniform(0.1, 5.0, n_t), jnp.float32)
     e = jnp.asarray(r.uniform(0.5, 4.0, n_k), jnp.float32)
     C = jnp.asarray(r.uniform(0.0, 3.0, (n_k, n_k)), jnp.float32)
-    if n_edges:
-        src = jnp.asarray(r.integers(0, n_t, n_edges))
-        dst = jnp.asarray(r.integers(0, n_t, n_edges))
-        s_oh = jax.nn.one_hot(src, n_t, dtype=jnp.float32)
-        d_oh = jax.nn.one_hot(dst, n_t, dtype=jnp.float32)
-    else:
-        s_oh = d_oh = jnp.zeros((0, n_t), jnp.float32)
-    return (oh, p, e, C, s_oh, d_oh)
+    src = jnp.asarray(r.integers(0, n_t, n_edges), jnp.int32)
+    dst = jnp.asarray(r.integers(0, n_t, n_edges), jnp.int32)
+    return (a, p, e, C, src, dst)
 
 
 BOTTLENECK_SHAPES = [
@@ -308,8 +302,8 @@ def test_solve_sdp_batch_kernel_backend_regression(sdp_instance):
 
 
 def test_rounding_kernel_backend_parity(e2e_solutions, sdp_instance):
-    """The one-hot bottleneck kernel scores every sample like the gather
-    path: identical argmin assignment and feasibility count."""
+    """The bottleneck kernel scores every sample like the gather path:
+    identical argmin assignment and feasibility count."""
     tg, cg = sdp_instance
     bqp, sols = e2e_solutions
     sol = sols["jnp"]
@@ -328,7 +322,7 @@ def test_rounding_kernel_backend_parity(e2e_solutions, sdp_instance):
 
 
 def test_rounding_kernel_backend_parity_edge_free():
-    """E = 0 lane: the kernel's inert padded edge row changes nothing."""
+    """E = 0 lane: the kernel skips its edge loop and changes nothing."""
     r = np.random.default_rng(3)
     tg = TaskGraph(p=r.uniform(0.5, 3.0, 6), edges=())
     cg = random_compute_graph(r, 3)

@@ -208,28 +208,21 @@ def test_token_account_rejects_bad_config():
 @given(instances())
 @settings(max_examples=25, deadline=None)
 def test_kernel_bottleneck_matches_eq2(inst):
-    """The one-hot bottleneck kernel == the index-gather gold evaluator."""
-    import jax
+    """The bottleneck kernel == the float64 host evaluator of Eq. 2."""
     import jax.numpy as jnp
 
     from repro.kernels.bottleneck import bottleneck_eval_fwd
     from repro.kernels.ref import bottleneck_eval_ref
 
     tg, cg, a = inst
-    n_t, n_k = tg.num_tasks, cg.num_machines
+    n_k = cg.num_machines
     batch = np.stack([a, (a + 1) % n_k, (a + 2) % n_k])
     gold = bottleneck_time_batch(tg, cg, batch)
 
-    oh = jax.nn.one_hot(jnp.asarray(batch), n_k, dtype=jnp.float32)
-    if tg.edges:
-        src = jnp.asarray([i for i, _ in tg.edges])
-        dst = jnp.asarray([j for _, j in tg.edges])
-        src_oh = jax.nn.one_hot(src, n_t, dtype=jnp.float32)
-        dst_oh = jax.nn.one_hot(dst, n_t, dtype=jnp.float32)
-    else:
-        src_oh = dst_oh = jnp.zeros((0, n_t), jnp.float32)
-    args = (oh, jnp.asarray(tg.p), jnp.asarray(cg.e), jnp.asarray(cg.C),
-            src_oh, dst_oh)
+    src = jnp.asarray([i for i, _ in tg.edges], jnp.int32)
+    dst = jnp.asarray([j for _, j in tg.edges], jnp.int32)
+    args = (jnp.asarray(batch, jnp.int32), jnp.asarray(tg.p),
+            jnp.asarray(cg.e), jnp.asarray(cg.C), src, dst)
     got = bottleneck_eval_fwd(*args, interpret=True)
     want = bottleneck_eval_ref(*args)
     np.testing.assert_allclose(np.asarray(got), gold, rtol=1e-4, atol=1e-4)
@@ -269,8 +262,10 @@ def test_kernel_compress_error_feedback(seed, n, l, frac):
                                     interpret=True)
     rmsg, rresid = int8_roundtrip_ref(delta, scale)
     assert np.array_equal(np.asarray(msg), np.asarray(rmsg))
-    np.testing.assert_allclose(np.asarray(resid), np.asarray(rresid),
-                               atol=2e-7)
+    # DESIGN §12: the residual may differ by 1 ulp of |delta| (FMA
+    # contraction of q·scale into the subtraction on either path)
+    ulp = np.spacing(np.abs(np.asarray(delta)))
+    assert np.all(np.abs(np.asarray(resid) - np.asarray(rresid)) <= ulp)
     assert np.all(np.abs(np.asarray(resid))
                   <= np.asarray(scale)[:, None] * 0.5 + 1e-7)
 
