@@ -69,6 +69,19 @@ BACKENDS = ("auto", "reference", "stacked", "sharded")
 MIX_BACKENDS = ("auto", "segment_sum", "pallas")
 COMPRESS_BACKENDS = ("auto", "jnp", "pallas")
 
+# Names the profiler records.  Device scopes (``jax.named_scope``) go into
+# the HLO metadata of every op a stage traces, its backward pass included
+# (``transpose(jvp(fl.local))``); host spans are ``TraceAnnotation``s on the
+# device trace's clock.  One name per stage, the same in every engine.
+STAGE_LOCAL = "fl.local"            # local SGD: forward, backward, SGDM
+STAGE_COMPRESS = "fl.compress"      # delta compression with error feedback
+STAGE_MIX = "fl.mix"                # the gossip exchange W · messages
+STAGE_HALO = "fl.mix.halo"          # sharded: the boundary rows' all_gather
+STAGE_AGGREGATE = "fl.aggregate"    # self-weight combine
+SPAN_ROUND = "fl.round"             # step_round (a profiler step)
+SPAN_DISPATCH = "fl.round.dispatch" # each jitted call a round issues
+SPAN_READBACK = "fl.round.readback" # the host sync on the round's loss
+
 
 @dataclasses.dataclass
 class GossipConfig:
@@ -180,6 +193,12 @@ class GossipTrainer:
 
     ``dropped_samples`` counts samples truncated away by the even-chunk
     stacking of uneven shards (0 when all shards have equal length).
+
+    Under ``jax.profiler`` a round shows as a ``fl.round`` step holding a
+    ``fl.round.dispatch`` span per jitted call and, on the stacked and
+    sharded engines, one ``fl.round.readback`` span for the loss's host
+    sync; on the device its ops sit under the stage scopes ``fl.local``,
+    ``fl.compress``, ``fl.mix`` (``fl.mix.halo``) and ``fl.aggregate``.
     """
 
     def __init__(
@@ -292,9 +311,11 @@ class GossipTrainer:
             self._grad = jax.jit(jax.value_and_grad(loss_fn))
 
     def _dispatch(self, fn, *args):
-        """Issue a jitted call, counting it toward ``last_round_dispatches``."""
+        """Issue a jitted call, counting it toward ``last_round_dispatches``
+        and recording it as a ``fl.round.dispatch`` span."""
         self._jit_calls += 1
-        return fn(*args)
+        with jax.profiler.TraceAnnotation(SPAN_DISPATCH):
+            return fn(*args)
 
     # -- backend resolution -------------------------------------------------
     @staticmethod
@@ -348,6 +369,7 @@ class GossipTrainer:
         )
 
         if not use_kernel:
+            @jax.named_scope(STAGE_COMPRESS)
             def compress(params, residual):
                 delta = jax.tree.map(jnp.add, params, residual)
                 msgs = jax.vmap(comp.roundtrip)(delta)
@@ -382,6 +404,7 @@ class GossipTrainer:
                 )
             return msg.reshape(x.shape), resid.reshape(x.shape)
 
+        @jax.named_scope(STAGE_COMPRESS)
         def compress(params, residual):
             delta = jax.tree.map(jnp.add, params, residual)
             leaves, treedef = jax.tree.flatten(delta)
@@ -524,6 +547,7 @@ class GossipTrainer:
             )
             return (params, opt_state, cursor, epoch, perm), losses
 
+        @jax.named_scope(STAGE_LOCAL)
         def local_scan(params, opt_state, cursor, epoch, perm, xs, ys, keys):
             # Full unroll: XLA CPU optimizes loop bodies poorly (a rolled
             # scan body runs ~5x slower here); local_steps is single-digit,
@@ -586,12 +610,14 @@ class GossipTrainer:
                 msgs = params
             else:
                 msgs, residual = compress_stage(params, residual)
-            incoming = mix(msgs)
-            params = jax.tree.map(
-                lambda p, m: self_w.reshape((n,) + (1,) * (p.ndim - 1)) * p + m,
-                params,
-                incoming,
-            )
+            with jax.named_scope(STAGE_MIX):
+                incoming = mix(msgs)
+            with jax.named_scope(STAGE_AGGREGATE):
+                params = jax.tree.map(
+                    lambda p, m: self_w.reshape((n,) + (1,) * (p.ndim - 1)) * p + m,
+                    params,
+                    incoming,
+                )
             state = (params, opt_state, cursor, epoch, perm, residual)
             return state, jnp.mean(losses)
 
@@ -604,7 +630,8 @@ class GossipTrainer:
         self._state, mean_loss = self._dispatch(
             self._round_jit, self._state, *self._data
         )
-        return float(mean_loss)
+        with jax.profiler.TraceAnnotation(SPAN_READBACK):
+            return float(mean_loss)
 
     # ======================================================================
     # Sharded engine: the stacked round under shard_map over a user mesh
@@ -794,6 +821,7 @@ class GossipTrainer:
 
             b_idx = ec["b_idx"][0]
 
+            @jax.named_scope(STAGE_HALO)
             def gather_halo(flat):
                 # (B, Lf) boundary rows -> (S·B, Lf) halo from every shard
                 rows = jnp.take(flat, b_idx, axis=0)
@@ -801,21 +829,23 @@ class GossipTrainer:
                     rows, USER_AXIS, axis=0, tiled=False
                 ).reshape(halo_rows, flat.shape[1])
 
-            if mix_backend == "segment_sum":
-                i_src, i_dst, i_w = ec["i_src"][0], ec["i_dst"][0], ec["i_w"][0]
-                x_src, x_dst, x_w = ec["x_src"][0], ec["x_dst"][0], ec["x_w"][0]
+            @jax.named_scope(STAGE_MIX)
+            def mix(msgs):
+                if mix_backend == "segment_sum":
+                    i_src, i_dst, i_w = ec["i_src"][0], ec["i_dst"][0], ec["i_w"][0]
+                    x_src, x_dst, x_w = ec["x_src"][0], ec["x_dst"][0], ec["x_w"][0]
 
-                def mix_leaf(msg):
-                    flat = msg.reshape(m, -1)
-                    inc = gossip_mix_segment_ref(flat, i_src, i_dst, i_w, m)
-                    if halo_rows:
-                        inc = inc + gossip_mix_segment_ref(
-                            gather_halo(flat), x_src, x_dst, x_w, m
-                        )
-                    return inc.reshape(msg.shape)
+                    def mix_leaf(msg):
+                        flat = msg.reshape(m, -1)
+                        inc = gossip_mix_segment_ref(flat, i_src, i_dst, i_w, m)
+                        if halo_rows:
+                            inc = inc + gossip_mix_segment_ref(
+                                gather_halo(flat), x_src, x_dst, x_w, m
+                            )
+                        return inc.reshape(msg.shape)
 
-                incoming = jax.tree.map(mix_leaf, msgs)
-            else:
+                    return jax.tree.map(mix_leaf, msgs)
+
                 wb = ec["Wb"][0]
 
                 def mix_block(X, bl):
@@ -828,14 +858,16 @@ class GossipTrainer:
                         X, wb, block_len=bl, interpret=interpret
                     )
 
-                incoming = _mix_leaves(msgs, m, mix_block, halo_rows)
+                return _mix_leaves(msgs, m, mix_block, halo_rows)
 
-            params = jax.tree.map(
-                lambda p, inc: (
-                    self_w.reshape((m,) + (1,) * (p.ndim - 1)) * p + inc
-                ),
-                params, incoming,
-            )
+            incoming = mix(msgs)
+            with jax.named_scope(STAGE_AGGREGATE):
+                params = jax.tree.map(
+                    lambda p, inc: (
+                        self_w.reshape((m,) + (1,) * (p.ndim - 1)) * p + inc
+                    ),
+                    params, incoming,
+                )
             # Padding users trained on zeros; the mask drops them from the
             # round loss, and every real user contributes exactly once.
             loss_sum = jax.lax.psum(
@@ -862,18 +894,20 @@ class GossipTrainer:
         self._state, mean_loss = self._dispatch(
             self._round_jit, self._state, *self._sharded_args
         )
-        return float(mean_loss)
+        with jax.profiler.TraceAnnotation(SPAN_READBACK):
+            return float(mean_loss)
 
     # -- public entry point --------------------------------------------------
     def step_round(self) -> dict:
         """One gossip round: local training + exchange + aggregate."""
         calls_before = self._jit_calls
-        if self.backend == "stacked":
-            mean_loss = self._step_round_stacked()
-        elif self.backend == "sharded":
-            mean_loss = self._step_round_sharded()
-        else:
-            mean_loss = self._step_round_reference()
+        with jax.profiler.StepTraceAnnotation(SPAN_ROUND, step_num=self.round):
+            if self.backend == "stacked":
+                mean_loss = self._step_round_stacked()
+            elif self.backend == "sharded":
+                mean_loss = self._step_round_sharded()
+            else:
+                mean_loss = self._step_round_reference()
         self.last_round_dispatches = self._jit_calls - calls_before
         self.round += 1
         return {
