@@ -357,9 +357,12 @@ class GossipTrainer:
         wire carries, and the new residual is ``delta - msgs``.  On the
         pallas lane the sparsify/quantize decision and the residual come
         out of ONE stream of the stacked delta per leaf
-        (``kernels/compress.py``); the per-row statistics (top-k threshold,
-        int8 scale) are tiny jnp reductions.  Compressors without a fused
-        kernel fall back to the jnp path.
+        (``kernels/compress.py``).  The per-row statistics are the int8
+        scale, a tiny max reduction, and the top-k threshold: the exact
+        k-th largest |x| of every row of every leaf from one radix select
+        (``topk_thresholds``): 8 fused compare-and-count passes over the
+        delta, 4 bits a pass, in one loop for all leaves, no sort.  Compressors without a
+        fused kernel fall back to the jnp path.
         """
         from repro.train.compression import Int8, TopK
 
@@ -377,40 +380,41 @@ class GossipTrainer:
 
             return compress
 
-        from repro.kernels.compress import int8_roundtrip_fwd, topk_mask_fwd
+        from repro.kernels.compress import (
+            int8_roundtrip_fwd,
+            topk_mask_fwd,
+            topk_thresholds,
+        )
 
         interpret = interpret_mode()
-        is_topk = isinstance(comp, TopK)
 
-        def one_leaf(x):
-            # Leading axis is whatever population this stage sees: all N_T
-            # users (stacked) or one shard's block (sharded).
-            rows = x.shape[0]
-            flat = x.reshape(rows, -1)
-            L = flat.shape[1]
-            bl = compress_block_len(rows, L)
-            if is_topk:
-                kk = max(1, int(comp.fraction * L))
-                vals, _ = jax.lax.top_k(jnp.abs(flat), kk)
-                msg, resid = topk_mask_fwd(
-                    flat, vals[:, -1], block_len=bl, interpret=interpret
-                )
-            else:
-                scale = jnp.maximum(
-                    jnp.max(jnp.abs(flat), axis=1), 1e-12
-                ) / 127.0
-                msg, resid = int8_roundtrip_fwd(
-                    flat, scale, block_len=bl, interpret=interpret
-                )
-            return msg.reshape(x.shape), resid.reshape(x.shape)
+        def rowstats(flats):
+            if isinstance(comp, TopK):
+                ks = [max(1, int(comp.fraction * f.shape[1])) for f in flats]
+                return topk_thresholds(flats, ks)
+            return [
+                jnp.maximum(jnp.max(jnp.abs(f), axis=1), 1e-12) / 127.0
+                for f in flats
+            ]
+
+        kernel = topk_mask_fwd if isinstance(comp, TopK) else int8_roundtrip_fwd
 
         @jax.named_scope(STAGE_COMPRESS)
         def compress(params, residual):
             delta = jax.tree.map(jnp.add, params, residual)
             leaves, treedef = jax.tree.flatten(delta)
-            outs = [one_leaf(l) for l in leaves]
-            msgs = treedef.unflatten([o[0] for o in outs])
-            resid = treedef.unflatten([o[1] for o in outs])
+            # Leading axis is whatever population this stage sees: all N_T
+            # users (stacked) or one shard's block (sharded).
+            flats = [l.reshape(l.shape[0], -1) for l in leaves]
+            outs = [
+                kernel(f, stat, block_len=compress_block_len(*f.shape),
+                       interpret=interpret)
+                for f, stat in zip(flats, rowstats(flats))
+            ]
+            msgs = treedef.unflatten(
+                [m.reshape(l.shape) for (m, _), l in zip(outs, leaves)])
+            resid = treedef.unflatten(
+                [r.reshape(l.shape) for (_, r), l in zip(outs, leaves)])
             return msgs, resid
 
         return compress

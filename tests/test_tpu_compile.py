@@ -15,6 +15,7 @@ imports every test file.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +26,7 @@ from repro.kernels.compress import (
     compress_block_len,
     int8_roundtrip_fwd,
     topk_mask_fwd,
+    topk_threshold,
 )
 from repro.kernels.gossip_mix import (
     gossip_mix_all_fwd,
@@ -134,6 +136,21 @@ def test_compress_compiles(compile_for_chip, kernel, users, length):
         lambda X, s: kernel(X, s, block_len=bl),
         ((users, length), F32), ((users,), F32),
     )
+
+
+@pytest.mark.parametrize("users", USERS)
+@pytest.mark.parametrize("length", CNN_LEAVES)
+def test_topk_select_and_mask_compile_without_a_sort(compile_for_chip, users,
+                                                     length):
+    """The compress stage's top-k path for one leaf: the radix select's
+    threshold into the mask kernel, with no sort left for the chip."""
+    k = max(1, int(0.05 * length))
+    bl = compress_block_len(users, length)
+    compiled = compile_for_chip(
+        lambda X: topk_mask_fwd(X, topk_threshold(X, k), block_len=bl),
+        ((users, length), F32),
+    )
+    assert re.search(r"= \S+ sort\(", compiled.as_text()) is None
 
 
 @pytest.mark.parametrize(
