@@ -7,11 +7,12 @@ Runs the cell, for each seed and each variant, in this one process (so
 set-up compiles once): ``program``, as the benchmark runs it; ``control``,
 which must come out not correct (the configuration's ``control``: the
 reference at the next precision below in the program's place); or the
-name of a fault of ``faults.py``, planted under the program.  A short window at the cell's own load is
-enough to produce the answers the check compares.  Prints one line per
-run with every number the check reads, compared or not, and the largest
-and smallest reading of each number per variant, which bracket the
-limits.
+name of a fault, planted under the program: one of the kind's own
+(``faults_<kind>.py``) or a shared one (``faults.py``).  A short window
+at the cell's own load is enough to produce the answers the check
+compares.  Prints one line per run with every number the check reads,
+compared or not, and the largest and smallest reading of each number per
+variant, which bracket the limits.
 """
 
 from __future__ import annotations
@@ -38,6 +39,7 @@ def main(argv=None) -> int:
                     help="comma-separated seeds, read in place of --seeds/--first-seed")
     args = ap.parse_args(argv)
     cell = run.load_cell(args.workload)
+    kind = cell["config_data"]["kind"]
     run.add_paths()
     try:
         devices = run.require_chips(int(cell["chips"]))
@@ -51,8 +53,8 @@ def main(argv=None) -> int:
     for variant in args.variants.split(","):
         for seed in seeds:
             vals: dict = {}
-            if variant in faults.FAULTS:
-                with faults.planted(variant):
+            if variant in faults.names(kind):
+                with faults.planted(variant, kind=kind):
                     out = run.run_cell(cell, seed, args.seconds, False, devices,
                                        readings=vals)
             else:

@@ -1,8 +1,8 @@
 """Device time of the compression stage per round (device trace): every op
-the trainer's ``fl.compress`` scope holds (the delta add, the top-k, the
-mask kernels, the reshapes between them), over the ``fl.round`` spans of
-the traced window.  It follows the stage whatever implements it, where
-``compress_device_ms_per_round.fl`` finds two kernels by their shapes."""
+the trainer's ``fl.compress`` scope holds (the delta add, the top-k
+threshold select, the mask kernels, the reshapes between them), over the
+``fl.round`` spans of the traced window.  It follows the stage whatever
+implements it."""
 
 import program_trace
 

@@ -18,13 +18,13 @@ from the same ``.xplane.pb`` that ``trace_reduce.load`` reads:
     ``fl.round.dispatch`` around each jitted call and
     ``fl.round.readback`` around the host's wait for the round's loss.
 
-``load`` takes the device ops, programs and window of
-``trace_reduce.load`` and adds each op name's scope path (read once per
-name, not once per event) and the ``fl.*`` host spans, all as
-``(name, start_ns, end_ns)`` tuples on the profiler's clock.  Everything
-else is arithmetic on those tuples, tested on a synthetic trace.  A trace
-with no device ops (a CPU run) or none of the program's spans (a program
-that records none) reads as nothing.
+``load`` takes the device ops, programs, window and ``fl.*`` host spans
+of ``trace_reduce.load`` and adds each op name's scope path (read once
+per name, not once per event), all as ``(name, start_ns, end_ns)``
+tuples on the profiler's clock.  Everything else is arithmetic on those
+tuples, tested on a synthetic trace.  A trace with no device ops (a CPU
+run) or none of the program's spans (a program that records none) reads
+as nothing.
 """
 
 from __future__ import annotations
@@ -352,21 +352,12 @@ def op_scopes(buf: bytes) -> dict[str, str]:
 
 def load(trace_dir, tr) -> ProgramTrace:
     """The newest trace under ``trace_dir`` as a ``ProgramTrace``: the device
-    ops, programs and window of ``tr`` (``trace_reduce.load`` of the same
-    trace), each op's scope path, and the program's host spans."""
-    from jax.profiler import ProfileData
-
-    path = trace_reduce.find_xplane(str(trace_dir))
-    buf = pathlib.Path(path).read_bytes()
-    host: list[Span] = []
-    for plane in ProfileData.from_serialized_xspace(buf).planes:
-        if plane.name.startswith("/host:"):
-            for line in plane.lines:
-                for ev in line.events:
-                    if ev.name.startswith(PREFIX):
-                        host.append((ev.name, ev.start_ns, ev.start_ns + ev.duration_ns))
+    ops, programs, window and program's host spans of ``tr``
+    (``trace_reduce.load`` of the same trace) and each op's scope path."""
+    buf = pathlib.Path(trace_reduce.find_xplane(str(trace_dir))).read_bytes()
     return ProgramTrace(ops=tr.ops, op_names=op_scopes(buf), modules=tr.modules,
-                        host=host, t0=tr.t0, t1=tr.t1)
+                        host=[sp for sp in tr.host if sp[0].startswith(PREFIX)],
+                        t0=tr.t0, t1=tr.t1)
 
 
 def get(ctx) -> ProgramTrace | None:

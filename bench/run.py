@@ -11,6 +11,39 @@ reference.  Each metric is a reader of its own (``bench/metrics/<name>.py``):
 ``--trace 0`` reports the cell's end-to-end metrics, ``--trace 1`` traces
 the window and reports its per-layer metrics.
 
+A runner is the class ``Runner(ctx)`` of ``bench/runners/<kind>.py``
+(``ctx``: a ``Context``), with four methods that the harness calls in
+this order:
+
+  - ``setup()``: build the system from ``ctx.seed`` and warm every shape
+    the traffic uses; this is set-up, timed as ``setup_s``;
+  - ``serve(seconds)``: the measured window.  It sets ``ctx.records``:
+    ``window_s`` (the window's seconds on the host clock), ``attempted``
+    and ``failed`` (rounds or requests, and those that failed), and
+    whatever the cell's end-to-end readers read (``fl_samples_per_s``:
+    ``samples``).  It wraps each round in ``ctx.span("bench.round")``,
+    which the per-round readers count;
+  - ``release()``: free the program's state before the check;
+  - ``check()``: compare what the window produced with the plain
+    reference; returns ``[(name, value, limit), ...]``, every number the
+    comparison read, with ``None`` as the limit of a number read but not
+    compared.  The run is correct where every compared value is finite
+    and at most its limit, nothing failed and nothing compiled in the
+    window.
+
+By the end of ``serve`` the runner has set the ``ctx.counters`` that the
+shared per-layer readers of its cells take: ``users`` and
+``params_per_user`` (``mix_roofline.fl``), ``samples_per_round`` and
+``train_flops_per_sample`` (``fl_step_mfu``, from the kind's own count
+functions).
+
+A configuration of a new kind joins as new files: its configuration, its
+runner, its plain reference (``bench/reference/``), its traffic mixes,
+readers of its own metrics, its count functions (``bench/flops_<kind>.py``)
+and its faults (``bench/faults_<kind>.py``, see ``faults.py``); and its
+cells' names are added to the ``workloads`` lists of the shared metrics
+they report.  Nothing else changes.
+
 A run needs the accelerator: without a TPU, or with fewer chips than the
 cell asks for, it exits 2 and prints no result.  JAX's persistent
 compilation cache is ``<checkout>/.jax_cache``.  The last lines on standard
@@ -72,6 +105,11 @@ def load_cell(workload: str, spec: dict | None = None) -> dict:
              or ("workloads" not in m and m["moves"] in moved)]
     cell["end_to_end"], cell["per_layer"] = e2e, layer
     return cell
+
+
+def load_runner(kind: str):
+    """The runner module of a configuration's ``kind``."""
+    return load_module(BENCH / "runners" / f"{kind}.py", "bench_runner_" + kind)
 
 
 def make_cell(entry: dict, config_file, traffic: str) -> dict:
@@ -194,8 +232,7 @@ def run_cell(cell, seed, seconds, trace, devices, variant="program",
 
     clock = CompileClock()
     ctx = Context(cell, seed, trace, devices, clock, variant)
-    runner = load_module(BENCH / "runners" / f"{ctx.config['kind']}.py",
-                         "bench_runner_" + ctx.config["kind"]).Runner(ctx)
+    runner = load_runner(ctx.config["kind"]).Runner(ctx)
     runner.setup()
     ctx.setup_s = time.perf_counter() - T_START
     comp_total = clock.seconds_since(0.0)

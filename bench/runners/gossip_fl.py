@@ -10,7 +10,10 @@ by the configuration: a new seed then finds the round program in the
 cache.  Those rounds compile the round program and give the readings the
 reference is held to: each round's loss, the momentum after round 1 and
 the parameters' change after the last of them.  The window then calls
-``step_round`` back to back, a closed loop of rounds.
+``step_round`` back to back, a closed loop of rounds.  The counts the
+shared readers take (samples a round, parameters a user, training
+operations a sample) follow from the configuration's widths, so the
+constructor sets them.
 
 The program runs under the matmul precision its configuration states
 (``jax.default_matmul_precision``).  After the window the plain reference
@@ -51,9 +54,15 @@ def _median(values: list[float]) -> float:
 class Runner:
     def __init__(self, ctx):
         self.ctx = ctx
-        self.cfg = ctx.config
+        self.cfg = c = ctx.config
         self.tr = ctx.traffic
-        self.image = tuple(self.cfg["image"])
+        self.image = tuple(c["image"])
+        widths = (self.image, c["classes"], c["channels"], c["hidden"])
+        ctx.counters.update(
+            users=c["users"],
+            samples_per_round=c["users"] * self.tr["local_steps"] * c["batch"],
+            params_per_user=ctx.flops.cnn_param_count(*widths),
+            train_flops_per_sample=ctx.flops.cnn_train_flops(*widths))
 
     def _data(self):
         c = self.cfg
@@ -143,8 +152,7 @@ class Runner:
         self.ctx.counters["compress_backend"] = tr.compress_backend
 
     def serve(self, seconds: float):
-        c, t, ctx = self.cfg, self.tr, self.ctx
-        tr = self.trainer
+        ctx, tr = self.ctx, self.trainer
         if tr is None:          # the control has no window
             return
         rounds = bad = 0
@@ -156,15 +164,9 @@ class Runner:
                 rounds += 1
                 bad += not math.isfinite(loss)
             window = time.perf_counter() - t0
-        per_round = c["users"] * t["local_steps"] * c["batch"]
         ctx.records.update(window_s=window, completed=rounds, attempted=rounds,
-                           failed=bad, samples=rounds * per_round)
-        ctx.counters.update(rounds=rounds, samples_per_round=per_round,
-                            users=c["users"], image=self.image,
-                            classes=c["classes"], channels=c["channels"], hidden=c["hidden"],
-                            params_per_user=ctx.flops.cnn_param_count(
-                                self.image, c["classes"], c["channels"], c["hidden"]),
-                            dispatches_per_round=tr.last_round_dispatches)
+                           failed=bad, samples=rounds * ctx.counters["samples_per_round"])
+        ctx.counters.update(rounds=rounds, dispatches_per_round=tr.last_round_dispatches)
 
     def release(self):
         self.trainer = None

@@ -3,7 +3,10 @@
 import json
 import pathlib
 
+import pytest
+
 import flops
+import run
 
 
 def flops_dir():
@@ -44,3 +47,18 @@ def test_mix_bytes():
     ops, nbytes = flops.mix_cost(64, 552_714)
     assert ops == 2 * 64 * 64 * 552_714
     assert nbytes == 4 * (2 * 64 * 552_714 + 64 * 64)
+
+
+@pytest.mark.parametrize("traffic", ["fl_topk", "fl_dpsgd"])
+def test_the_cnn_runner_counts_training_operations_at_the_configured_widths(traffic):
+    cell = run.make_cell({"name": traffic, "chips": 1},
+                         flops_dir() / "configs" / "fl_cnn_cifar10_u64.json", traffic)
+    ctx = run.Context(cell, 2**31 + 7, False, None, None)
+    run.load_runner(cell["config_data"]["kind"]).Runner(ctx)
+    cfg = cell["config_data"]
+    want = flops.cnn_train_flops(tuple(cfg["image"]), cfg["classes"], cfg["channels"],
+                                 cfg["hidden"])
+    assert ctx.counters["train_flops_per_sample"] == want == 35_049_216
+    assert ctx.counters["params_per_user"] == 552_714
+    assert ctx.counters["samples_per_round"] == (
+        cfg["users"] * cell["traffic_data"]["local_steps"] * cfg["batch"])

@@ -1,6 +1,8 @@
 """BENCHMARK.json against the benchmark's contract, the files it names,
 the result line, and a run without the accelerator."""
 
+import copy
+import inspect
 import json
 import pathlib
 import re
@@ -62,8 +64,69 @@ def test_every_cell_reports_setup_another_end_to_end_and_a_layer(cell):
     assert c["per_layer"]
     for m in c["per_layer"]:
         assert m["moves"] in names
-    assert c["config_data"]["kind"] == "gossip_fl"
-    assert (ROOT / "bench" / "runners" / f"{c['config_data']['kind']}.py").is_file()
+    assert_runner_contract(c["config_data"]["kind"])
+
+
+def assert_runner_contract(kind):
+    """What the harness relies on in a runner (``run.py``'s docstring):
+    ``bench/runners/<kind>.py`` defines ``Runner(ctx)`` with ``setup()``,
+    ``serve(seconds)``, ``release()`` and ``check()``."""
+    assert (ROOT / "bench" / "runners" / f"{kind}.py").is_file(), kind
+    runner = run.load_runner(kind).Runner
+    inspect.signature(runner).bind(object())
+    for method, args in (("setup", 0), ("serve", 1), ("release", 0), ("check", 0)):
+        assert callable(getattr(runner, method, None)), (kind, method)
+        inspect.signature(getattr(runner, method)).bind(object(), *range(args))
+
+
+@pytest.mark.parametrize("kind", sorted(
+    p.stem for p in (ROOT / "bench" / "runners").glob("*.py")))
+def test_every_runner_keeps_the_runner_contract(kind):
+    assert_runner_contract(kind)
+
+
+def _metric_sets(cell):
+    return ({m["name"] for m in cell["end_to_end"]}, {m["name"] for m in cell["per_layer"]})
+
+
+# what the two CNN cells report at least; a later PR may add to them
+CNN_SETS = {
+    "fl_cnn64.topk": ({"setup_s", "fl_samples_per_s"},
+                      {"fl_step_mfu", "mix_roofline.fl", "device_idle_share.fl",
+                       "local_ms_per_round.fl", "compress_stage_ms_per_round.fl",
+                       "round_gap_ms.fl", "dispatch_ms_per_round.fl"}),
+    "fl_cnn64.dpsgd": ({"setup_s", "fl_samples_per_s"},
+                       {"fl_step_mfu", "mix_roofline.fl", "device_idle_share.fl",
+                        "local_ms_per_round.fl", "round_gap_ms.fl",
+                        "dispatch_ms_per_round.fl"}),
+}
+
+
+def test_a_configuration_of_another_kind_joins_by_new_files_and_list_entries(tmp_path):
+    before = {w["name"]: _metric_sets(run.load_cell(w["name"], SPEC)) for w in SPEC["workloads"]}
+    for name, (e2e, layer) in CNN_SETS.items():
+        assert e2e <= before[name][0] and layer <= before[name][1], name
+    assert not any("compress_device_ms_per_round.fl" in sets[1] for sets in before.values())
+
+    spec = copy.deepcopy(SPEC)
+    config = tmp_path / "lm_probe.json"
+    config.write_text(json.dumps({"kind": "gossip_lm_probe", "users": 16}))
+    spec["configs"].append({"name": "lm_probe", "source": "https://arxiv.org/abs/2305.05644",
+                            "file": str(config), "reduced": [], "why": "a second kind"})
+    spec["workloads"].append({"name": "lm_probe.dpsgd", "config": "lm_probe",
+                              "traffic": "fl_dpsgd", "chips": 1, "why": "a second kind"})
+    shared = next(m for m in spec["end_to_end"] if m["name"] == "fl_samples_per_s")
+    shared["workloads"].append("lm_probe.dpsgd")
+    spec["per_layer"].append({"name": "lm_probe_roofline", "unit": "%", "better": "higher",
+                              "source": "device_trace", "layer": "LM probe",
+                              "moves": "fl_samples_per_s", "workloads": ["lm_probe.dpsgd"]})
+
+    new = run.load_cell("lm_probe.dpsgd", spec)
+    assert new["config_data"]["kind"] == "gossip_lm_probe"
+    assert new["traffic_data"] == run.load_cell("fl_cnn64.dpsgd", SPEC)["traffic_data"]
+    assert _metric_sets(new) == ({"setup_s", "fl_samples_per_s"}, {"lm_probe_roofline"})
+    assert {w["name"]: _metric_sets(run.load_cell(w["name"], spec))
+            for w in SPEC["workloads"]} == before
 
 
 def test_result_line_keys_and_types(capsys):

@@ -10,6 +10,7 @@ import trace_reduce
 
 DATA = pathlib.Path(__file__).resolve().parent / "data" / "trace_small.json"
 NS = 1e-9
+CIFAR = (32, 32, 3)
 
 
 @pytest.fixture()
@@ -48,6 +49,33 @@ def test_idle_gaps_are_named_by_the_covering_span(tr):
     gaps = tr.idle_gaps(3)
     assert [g[0] for g in gaps] == ["bench.request", "bench.wait", "bench.request"]
     assert [g[1] for g in gaps] == pytest.approx([300 * NS, 100 * NS, 50 * NS])
+
+
+def test_idle_gaps_are_named_by_the_programs_host_spans():
+    # two round programs with the host reading the first round's loss back
+    # between them, under the benchmark's round span and the program's own
+    ops = {"/device:TPU:0": [("fusion.1", 0, 400), ("fusion.2", 600, 1000)]}
+    host = [("bench.window", 0, 1000), ("bench.round", 0, 560), ("bench.round", 560, 1000),
+            ("fl.round", 0, 550), ("fl.round.dispatch", 10, 30),
+            ("fl.round.readback", 30, 540), ("fl.round", 560, 1000)]
+    tr = trace_reduce.Trace(ops=ops, modules={}, host=host)
+    assert tr.idle_gaps(1) == [["fl.round.readback", pytest.approx(200 * NS)]]
+    assert len(tr.spans("bench.round")) == 2 and tr.window_s == pytest.approx(1000 * NS)
+
+
+def test_load_keeps_the_benchmarks_and_the_programs_host_spans(tmp_path):
+    import jax
+    import jax.numpy as jnp
+
+    with jax.profiler.trace(str(tmp_path)):
+        with jax.profiler.TraceAnnotation("bench.round"):
+            with jax.profiler.TraceAnnotation("fl.round.readback"):
+                jnp.ones(8).sum().block_until_ready()
+        with jax.profiler.TraceAnnotation("other.span"):
+            pass
+    names = {sp[0] for sp in trace_reduce.load(str(tmp_path)).host}
+    assert {"bench.round", "fl.round.readback"} <= names
+    assert "other.span" not in names
 
 
 def test_top_ops_sum_time_per_name(tr):
@@ -94,12 +122,19 @@ def test_kernels_are_found_by_their_signatures():
     ctx = _Ctx(tr, {"users": 64, "params_per_user": 552_714})
     ops, nbytes = flops.mix_cost(64, 552_714)
     assert _reader("mix_roofline.fl").read(ctx) == pytest.approx(100 * nbytes / 819e9 / 1e-6)
-    # the top-k sort and the mask kernel, not the permutation's sort; two rounds
-    assert _reader("compress_device_ms_per_round.fl").read(ctx) == pytest.approx(1e-3)
+
+
+def test_step_mfu_reads_the_runners_count_as_the_old_formula_did():
+    tr = _kernel_trace(["mix"])
+    train_flops = flops.cnn_train_flops(CIFAR, 10, [32, 64], [128, 64])
+    ctx = _Ctx(tr, {"samples_per_round": 2048, "train_flops_per_sample": train_flops})
+    # the formula before the count became the runner's counter
+    old = 100.0 * train_flops * (2 * 2048 / tr.window_s) / 197e12
+    assert _reader("fl_step_mfu").read(ctx) == old
+    assert old == pytest.approx(100 * 35_049_216 * 2 * 2048 / 100e-6 / 197e12)
 
 
 def test_a_reader_that_finds_nothing_returns_nothing():
     tr = _kernel_trace(["perm_sort"])
     ctx = _Ctx(tr, {"users": 64, "params_per_user": 552_714})
-    for name in ("mix_roofline.fl", "compress_device_ms_per_round.fl"):
-        assert _reader(name).read(ctx) is None
+    assert _reader("mix_roofline.fl").read(ctx) is None

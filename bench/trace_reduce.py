@@ -7,9 +7,11 @@ events, each as ``(name, start_ns, end_ns)`` on the profiler's clock:
   - device operations: the ``XLA Ops`` line of every ``/device:`` plane,
     one list per device;
   - device programs: the ``XLA Modules`` line of the same planes;
-  - host spans the benchmark itself records (``bench.*``
-    ``TraceAnnotation``s: the traced window, each request or round, each
-    wait of the load generator).
+  - host spans (``TraceAnnotation``s): those the benchmark itself records
+    (``bench.*``: the traced window, each request or round, each wait of
+    the load generator) and the program's own (``fl.*``: each round, its
+    dispatch and its readback), so that an idle gap is named by what the
+    program's host was doing in it.
 
 An operation's name on the TPU is the HLO instruction's text, e.g.
 ``%body.16 = (f32[121,16], ...) custom-call(f32[121,121], ...),
@@ -29,7 +31,7 @@ import re
 
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
-HOST_PREFIX = "bench."
+HOST_PREFIXES = ("bench.", "fl.")
 WINDOW_SPAN = "bench.window"
 CONTAINER = re.compile(r"[)}\]] (while|conditional|call)\(")
 
@@ -49,7 +51,7 @@ def merge(intervals) -> list[tuple[float, float]]:
 
 @dataclasses.dataclass
 class Trace:
-    """Device ops and programs per device, and the benchmark's host spans."""
+    """Device ops and programs per device, and the host spans kept."""
 
     ops: dict[str, list[Span]]
     modules: dict[str, list[Span]]
@@ -109,7 +111,7 @@ class Trace:
         return out
 
     def spans(self, name: str) -> list[Span]:
-        """The benchmark's host spans of this name, inside the window."""
+        """The host spans of this name, inside the window."""
         return [sp for sp in self.host
                 if sp[0] == name and sp[2] > self.t0 and sp[1] < self.t1]
 
@@ -129,7 +131,7 @@ class Trace:
 
     def idle_gaps(self, n: int = 10) -> list[list]:
         """The ``n`` longest idle gaps of the first device, each named by
-        the benchmark's host span that covers its middle."""
+        the shortest host span that covers its middle."""
         if not self._busy:
             return []
         busy = self._busy[self.devices[0]]
@@ -141,7 +143,7 @@ class Trace:
         out = []
         for s, e in sorted(gaps, key=lambda g: g[0] - g[1])[:n]:
             mid = 0.5 * (s + e)
-            name = next((sp[0] for sp in host if sp[1] <= mid < sp[2]), "outside bench spans")
+            name = next((sp[0] for sp in host if sp[1] <= mid < sp[2]), "outside host spans")
             out.append([name, (e - s) * 1e-9])
         return out
 
@@ -173,7 +175,7 @@ def load(trace_dir: str) -> Trace:
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 for ev in line.events:
-                    if ev.name.startswith(HOST_PREFIX):
+                    if ev.name.startswith(HOST_PREFIXES):
                         s = ev.start_ns
                         host.append((ev.name, s, s + ev.duration_ns))
     return Trace(ops=ops, modules=modules, host=host)
