@@ -32,25 +32,32 @@ _MODULES = {
 
 ARCH_IDS = tuple(_MODULES)
 
+# Architectures that run on the gossip-FL trainer's own model
+# (``models/mla.py``) rather than through ``build_model``.
+FL_ARCH_MODULES = {
+    "deepseek-v2-lite": "repro.configs.deepseek_v2_lite",
+}
+_ALL = {**_MODULES, **FL_ARCH_MODULES}
+
 
 def _normalize(arch_id: str) -> str:
     a = arch_id.lower().replace("_", "-")
-    if a not in _MODULES:
+    if a not in _ALL:
         # allow python-module style ids like "mamba2_1_3b"
-        for k in _MODULES:
+        for k in _ALL:
             if k.replace("-", "").replace(".", "") == a.replace("-", "").replace(".", ""):
                 return k
-        raise KeyError(f"unknown arch {arch_id!r}; known: {ARCH_IDS}")
+        raise KeyError(f"unknown arch {arch_id!r}; known: {tuple(_ALL)}")
     return a
 
 
 def get_config(arch_id: str) -> ModelConfig:
-    mod = importlib.import_module(_MODULES[_normalize(arch_id)])
+    mod = importlib.import_module(_ALL[_normalize(arch_id)])
     return mod.config()
 
 
 def get_smoke_config(arch_id: str) -> ModelConfig:
-    mod = importlib.import_module(_MODULES[_normalize(arch_id)])
+    mod = importlib.import_module(_ALL[_normalize(arch_id)])
     return mod.smoke_config()
 
 

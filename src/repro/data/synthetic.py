@@ -76,8 +76,25 @@ class ImageDataset:
         ]
 
 
-def stack_shards(shards: list[ImageDataset]) -> tuple[np.ndarray, np.ndarray]:
-    """Stack per-user shards into ``(N_T, chunk, H, W, C)`` / ``(N_T, chunk)``.
+@dataclasses.dataclass
+class TokenDataset:
+    """One FL user's token sequences for next-token training: ``x`` (N, S)
+    int32 ids and ``y`` (N, S) the ids that follow them."""
+
+    x: np.ndarray
+    y: np.ndarray
+
+    @classmethod
+    def from_sequences(cls, tokens: np.ndarray) -> "TokenDataset":
+        """From (N, S + 1) sequences: inputs are all but the last id."""
+        tokens = np.asarray(tokens, np.int32)
+        return cls(tokens[:, :-1], tokens[:, 1:])
+
+
+def stack_shards(shards: list[ImageDataset] | list[TokenDataset]
+                 ) -> tuple[np.ndarray, np.ndarray]:
+    """Stack per-user shards into ``(N_T, chunk, H, W, C)`` / ``(N_T, chunk)``
+    (token shards: ``(N_T, chunk, S)`` twice).
 
     The stacked gossip engine keeps every user's data in one device array,
     so shards are truncated to the common minimum length (``np.array_split``

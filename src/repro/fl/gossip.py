@@ -32,6 +32,14 @@ Three interchangeable engines run the learning (DESIGN.md §8, §13):
     one jitted dispatch per round; per-round losses match the stacked
     backend to fp32 at any mesh size (pinned in tests/test_shard_fl.py).
 
+A model whose base is frozen and shared by every user (LoRA fine-tuning)
+passes ``frozen``: the base enters each round program as an argument, never
+as a constant, and only the per-user parameters (the adapters) are
+trained and mixed.  Its ``user_losses`` hook then takes every user's
+parameters and batch at once and returns each user's loss, so layers that
+mix tokens across sequences (an expert layer's routing) see the whole
+round's tokens; the gradient of the losses' sum gives each user its own.
+
 Both engines draw identical data: shards are stacked to ``(N_T, chunk, …)``
 at construction and batches are index-gathers through a per-user epoch
 permutation derived from the jax PRNG (``fold_in(data_key, user, epoch)``),
@@ -194,6 +202,13 @@ class GossipTrainer:
     ``dropped_samples`` counts samples truncated away by the even-chunk
     stacking of uneven shards (0 when all shards have equal length).
 
+    ``frozen`` (a pytree the model reads but never trains, one copy for
+    all users) makes the loss ``loss_fn(params, batch, frozen)``; on the
+    stacked engine ``user_losses(stacked params, stacked batch, frozen) ->
+    (losses (N_T,), aux)`` replaces the per-user ``vmap`` of ``loss_fn``,
+    and ``aux_totals`` sums its ``aux`` counters on the device over every
+    local step of every round.
+
     Under ``jax.profiler`` a round shows as a ``fl.round`` step holding a
     ``fl.round.dispatch`` span per jitted call and, on the stacked and
     sharded engines, one ``fl.round.readback`` span for the loss's host
@@ -211,6 +226,8 @@ class GossipTrainer:
         seed: int = 0,
         backend: str | None = None,
         user_mesh: Any = None,   # launch.sharding.UserMesh ("sharded" only)
+        frozen: Any = None,
+        user_losses: Callable | None = None,
     ):
         self.g = task_graph
         self.cfg = cfg or GossipConfig()
@@ -218,6 +235,10 @@ class GossipTrainer:
         assert len(shards) == self.n
         self.shards = shards
         self.backend = self._resolve_backend(backend or self.cfg.backend)
+        if frozen is not None and self.backend == "sharded":
+            raise ValueError("a frozen base runs on the stacked or reference engine")
+        self._frozen = () if frozen is None else (frozen,)
+        self._user_losses = user_losses
         self.mix_backend = self._resolve_mix_backend(self.cfg.mix_backend)
         self.compress_backend = self._resolve_compress_backend(
             self.cfg.compress_backend
@@ -296,7 +317,7 @@ class GossipTrainer:
                 jnp.zeros(self.n, jnp.int32),                        # epoch
                 jnp.tile(jnp.arange(self._chunk, dtype=jnp.int32), (self.n, 1)),
                 residual,
-            )
+            ) + self._aux_zeros(stacked)
             self._round_jit = self._build_stacked_round()
         elif self.backend == "sharded":
             self._init_sharded(common, user_mesh)
@@ -309,6 +330,27 @@ class GossipTrainer:
             self._epoch = [0] * self.n
             self._perm = [np.arange(self._chunk) for _ in range(self.n)]
             self._grad = jax.jit(jax.value_and_grad(loss_fn))
+            self._frozen_dev = jax.tree.map(jnp.asarray, self._frozen)
+
+    def _aux_zeros(self, stacked) -> tuple:
+        """The ``user_losses`` counters' running totals, zero (none without
+        the hook)."""
+        if self._user_losses is None:
+            return ()
+        batch = {
+            "x": jax.ShapeDtypeStruct((self.n, self.cfg.batch_size) + self._xs.shape[2:],
+                                      self._xs.dtype),
+            "y": jax.ShapeDtypeStruct((self.n, self.cfg.batch_size) + self._ys.shape[2:],
+                                      self._ys.dtype),
+        }
+        _, aux = jax.eval_shape(self._user_losses, stacked, batch, *self._frozen)
+        return (jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), aux),)
+
+    @property
+    def aux_totals(self):
+        """The ``user_losses`` counters summed over every local step so far
+        (device arrays: reading them syncs)."""
+        return self._state[6] if len(self._state) > 6 else None
 
     def _dispatch(self, fn, *args):
         """Issue a jitted call, counting it toward ``last_round_dispatches``
@@ -460,7 +502,9 @@ class GossipTrainer:
                 "y": jnp.asarray(self._ys[i][idx]),
             }
             self._cursor[i] = lo + cfg.batch_size
-            loss, grads = self._dispatch(self._grad, self._params[i], batch)
+            loss, grads = self._dispatch(
+                self._grad, self._params[i], batch, *self._frozen_dev
+            )
             self._params[i], self.opt_state[i], _ = self.opt.update(
                 grads, self.opt_state[i], self._params[i]
             )
@@ -511,11 +555,13 @@ class GossipTrainer:
         """The shared local-training stage of one stacked round.
 
         Returns ``local_scan(params, opt_state, cursor, epoch, perm, xs,
-        ys, keys) -> ((params, opt_state, cursor, epoch, perm), losses)``
-        — ``cfg.local_steps`` of vmapped SGDM with the in-jit epoch
-        reshuffle, fully unrolled.  The per-user reshuffle keys ride in as
-        an ARGUMENT (not a closure) so the sharded engine can feed each
-        shard its own key block under ``shard_map``.  Extracted so the
+        ys, keys, *frozen) -> ((params, opt_state, cursor, epoch, perm),
+        losses)`` — ``cfg.local_steps`` of vmapped SGDM with the in-jit
+        epoch reshuffle, fully unrolled; with the ``user_losses`` hook one
+        loss call a step for every user, and ``(losses, aux)`` per step.
+        The per-user reshuffle keys ride in as an ARGUMENT (not a closure)
+        so the sharded engine can feed each shard its own key block under
+        ``shard_map``.  Extracted so the
         barrier-free trainer (``repro.fl.async_gossip``) traces the
         IDENTICAL math: that is what makes its degenerate case reproduce
         this engine's losses.
@@ -525,7 +571,7 @@ class GossipTrainer:
         opt = self.opt
         grad_fn = jax.value_and_grad(self._loss_fn)
 
-        def one_user(p, o, cur, ep, pm, x_u, y_u, key_u):
+        def next_rows(cur, ep, pm, key_u):
             wrap = cur + batch > chunk
             ep = ep + wrap.astype(ep.dtype)
             # The refresh runs every step (a vmapped branch would execute
@@ -537,7 +583,10 @@ class GossipTrainer:
             ).astype(pm.dtype)
             pm = jnp.where(wrap, pm_new, pm)
             cur = jnp.where(wrap, 0, cur)
-            idx = jax.lax.dynamic_slice(pm, (cur,), (batch,))
+            return cur, ep, pm, jax.lax.dynamic_slice(pm, (cur,), (batch,))
+
+        def one_user(p, o, cur, ep, pm, x_u, y_u, key_u):
+            cur, ep, pm, idx = next_rows(cur, ep, pm, key_u)
             loss, g = grad_fn(
                 p, {"x": jnp.take(x_u, idx, axis=0), "y": jnp.take(y_u, idx, axis=0)}
             )
@@ -551,12 +600,37 @@ class GossipTrainer:
             )
             return (params, opt_state, cursor, epoch, perm), losses
 
+        user_losses = self._user_losses
+
+        def local_step_stacked(xs, ys, keys, frozen, carry):
+            # every user's batch in one loss call: (losses, aux) per step
+            params, opt_state, cursor, epoch, perm = carry
+            cursor, epoch, perm, idx = jax.vmap(next_rows)(cursor, epoch, perm, keys)
+            take = jax.vmap(lambda a, i: jnp.take(a, i, axis=0))
+            stacked_batch = {"x": take(xs, idx), "y": take(ys, idx)}
+
+            def total(p):
+                losses, aux = user_losses(p, stacked_batch, *frozen)
+                return jnp.sum(losses), (losses, aux)
+
+            (_, out), g = jax.value_and_grad(total, has_aux=True)(params)
+            params, opt_state, _ = jax.vmap(opt.update)(g, opt_state, params)
+            return (params, opt_state, cursor + batch, epoch, perm), out
+
         @jax.named_scope(STAGE_LOCAL)
-        def local_scan(params, opt_state, cursor, epoch, perm, xs, ys, keys):
+        def local_scan(params, opt_state, cursor, epoch, perm, xs, ys, keys, *frozen):
             # Full unroll: XLA CPU optimizes loop bodies poorly (a rolled
             # scan body runs ~5x slower here); local_steps is single-digit,
             # so straight-line code costs little compile time and lets XLA
             # fuse across steps.
+            if user_losses is not None:
+                return jax.lax.scan(
+                    lambda carry, _: local_step_stacked(xs, ys, keys, frozen, carry),
+                    (params, opt_state, cursor, epoch, perm),
+                    None,
+                    length=cfg.local_steps,
+                    unroll=cfg.local_steps,
+                )
             return jax.lax.scan(
                 lambda carry, _: local_step(xs, ys, keys, carry),
                 (params, opt_state, cursor, epoch, perm),
@@ -574,7 +648,8 @@ class GossipTrainer:
         # The dataset is a jit ARGUMENT, not a closure constant: closed-over
         # arrays get inlined into the compiled executable (a second copy of
         # the full training set, again on every retrace).
-        self._data = (jnp.asarray(self._xs), jnp.asarray(self._ys))
+        self._data = (jnp.asarray(self._xs), jnp.asarray(self._ys)) + tuple(
+            jax.tree.map(jnp.asarray, self._frozen))
         user_keys = self._user_keys
         self_w = jnp.asarray(self._self_w)
         src = jnp.asarray(self._src)
@@ -605,11 +680,16 @@ class GossipTrainer:
 
         mix = mix_segment if mix_backend == "segment_sum" else mix_pallas
 
-        def round_fn(state, xs, ys):
-            params, opt_state, cursor, epoch, perm, residual = state
+        def round_fn(state, xs, ys, *frozen):
+            # ``frozen``: the shared base, an argument (never a constant)
+            params, opt_state, cursor, epoch, perm, residual, *aux = state
             (params, opt_state, cursor, epoch, perm), losses = local_scan(
-                params, opt_state, cursor, epoch, perm, xs, ys, user_keys
+                params, opt_state, cursor, epoch, perm, xs, ys, user_keys, *frozen
             )
+            if aux:             # the loss hook's counters, summed on the device
+                losses, step_aux = losses
+                aux = [jax.tree.map(lambda t, a: t + jnp.sum(a, axis=0),
+                                    aux[0], step_aux)]
             if comp is None:
                 msgs = params
             else:
@@ -622,11 +702,12 @@ class GossipTrainer:
                     params,
                     incoming,
                 )
-            state = (params, opt_state, cursor, epoch, perm, residual)
+            state = (params, opt_state, cursor, epoch, perm, residual, *aux)
             return state, jnp.mean(losses)
 
         # Buffer donation halves peak replica memory; the CPU backend does
-        # not implement donation and would warn on every call.
+        # not implement donation and would warn on every call.  The frozen
+        # base is never donated: every round reads it.
         donate = () if jax.default_backend() == "cpu" else (0,)
         return jax.jit(round_fn, donate_argnums=donate)
 

@@ -38,12 +38,15 @@ def dense_attention(
     causal: bool = True,
     window: int = 0,
     q_offset: int = 0,
+    scale: float | None = None,
 ) -> jnp.ndarray:
-    """Reference attention. q (B,Sq,H,D), k/v (B,Sk,Hkv,D) -> (B,Sq,H,D)."""
+    """Reference attention. q, k (B,S,H|Hkv,D), v (B,Sk,Hkv,Dv) -> (B,Sq,H,Dv).
+
+    ``scale`` multiplies the logits (default ``D**-0.5``)."""
     g = _split_heads(q, k, v)
     b, sq, h, d = q.shape
-    sk, hkv = k.shape[1], k.shape[2]
-    scale = 1.0 / math.sqrt(d)
+    sk, hkv, dv = k.shape[1], k.shape[2], v.shape[-1]
+    scale = 1.0 / math.sqrt(d) if scale is None else scale
     qg = q.reshape(b, sq, hkv, g, d)
     logits = jnp.einsum("bqhgd,bkhd->bhgqk", qg.astype(jnp.float32),
                         k.astype(jnp.float32)) * scale
@@ -58,7 +61,7 @@ def dense_attention(
         logits = jnp.where(mask[None, None, None], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v.astype(jnp.float32))
-    return out.reshape(b, sq, h, d).astype(q.dtype)
+    return out.reshape(b, sq, h, dv).astype(q.dtype)
 
 
 def chunked_attention(
@@ -72,22 +75,25 @@ def chunked_attention(
     kv_chunk: int = 1024,
     q_offset: int = 0,
     return_lse: bool = False,
+    scale: float | None = None,
 ):
     """Flash-style attention in pure jnp (see module docstring).
 
     Causal triangular skipping: query block t only scans kv chunks that can
     contain unmasked keys, so compiled FLOPs follow the true mask area.
+    ``v`` may have a head dim of its own; ``scale`` as in
+    ``dense_attention``.
     """
     b, sq, h, d = q.shape
-    sk, hkv = k.shape[1], k.shape[2]
+    sk, hkv, dv = k.shape[1], k.shape[2], v.shape[-1]
     g = _split_heads(q, k, v)
-    scale = 1.0 / math.sqrt(d)
+    scale = 1.0 / math.sqrt(d) if scale is None else scale
     q_block = min(q_block, sq)
     kv_chunk = min(kv_chunk, sk)
     assert sq % q_block == 0 and sk % kv_chunk == 0, (sq, q_block, sk, kv_chunk)
 
     kc = k.reshape(b, sk // kv_chunk, kv_chunk, hkv, d)
-    vc = v.reshape(b, sk // kv_chunk, kv_chunk, hkv, d)
+    vc = v.reshape(b, sk // kv_chunk, kv_chunk, hkv, dv)
 
     outs = []
     lses = []
@@ -128,13 +134,13 @@ def chunked_attention(
 
         m0 = jnp.full((b, hkv, g, q_block), -jnp.inf, dtype=jnp.float32)
         l0 = jnp.zeros((b, hkv, g, q_block), dtype=jnp.float32)
-        a0 = jnp.zeros((b, hkv, g, q_block, d), dtype=jnp.float32)
+        a0 = jnp.zeros((b, hkv, g, q_block, dv), dtype=jnp.float32)
         ks = jnp.moveaxis(kc[:, c0:c1], 1, 0)   # (nc, B, kc, Hkv, d)
         vs = jnp.moveaxis(vc[:, c0:c1], 1, 0)
         cidxs = jnp.arange(c0, c1)
         (m, l, acc), _ = jax.lax.scan(step, (m0, l0, a0), (ks, vs, cidxs))
         out = acc / jnp.maximum(l[..., None], 1e-30)
-        out = jnp.moveaxis(out, 3, 1).reshape(b, q_block, h, d)
+        out = jnp.moveaxis(out, 3, 1).reshape(b, q_block, h, dv)
         outs.append(out.astype(q.dtype))
         if return_lse:
             lses.append(m + jnp.log(jnp.maximum(l, 1e-30)))  # (B,Hkv,g,qb)
@@ -153,44 +159,44 @@ def chunked_attention(
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def flash_attention_jnp(q, k, v, causal=True, window=0, q_block=1024,
-                        kv_chunk=1024, q_offset=0):
-    out, _ = _flash_fwd(q, k, v, causal, window, q_block, kv_chunk, q_offset)
+                        kv_chunk=1024, q_offset=0, scale=None):
+    out, _ = _flash_fwd(q, k, v, causal, window, q_block, kv_chunk, q_offset, scale)
     return out
 
 
-def _flash_fwd(q, k, v, causal, window, q_block, kv_chunk, q_offset):
+def _flash_fwd(q, k, v, causal, window, q_block, kv_chunk, q_offset, scale):
     out, lse = chunked_attention(
         q, k, v, causal=causal, window=window, q_block=q_block,
-        kv_chunk=kv_chunk, q_offset=q_offset, return_lse=True,
+        kv_chunk=kv_chunk, q_offset=q_offset, return_lse=True, scale=scale,
     )
     return out, (q, k, v, out, lse)
 
 
-def _fwd_rule(q, k, v, causal, window, q_block, kv_chunk, q_offset):
-    out, res = _flash_fwd(q, k, v, causal, window, q_block, kv_chunk, q_offset)
+def _fwd_rule(q, k, v, causal, window, q_block, kv_chunk, q_offset, scale):
+    out, res = _flash_fwd(q, k, v, causal, window, q_block, kv_chunk, q_offset, scale)
     return out, res
 
 
-def _bwd_rule(causal, window, q_block, kv_chunk, q_offset, res, dout):
+def _bwd_rule(causal, window, q_block, kv_chunk, q_offset, scale, res, dout):
     q, k, v, out, lse = res
     b, sq, h, d = q.shape
-    sk, hkv = k.shape[1], k.shape[2]
+    sk, hkv, dv = k.shape[1], k.shape[2], v.shape[-1]
     g = h // hkv
-    scale = 1.0 / math.sqrt(d)
+    scale = 1.0 / math.sqrt(d) if scale is None else scale
     q_block = min(q_block, sq)
     kv_chunk = min(kv_chunk, sk)
 
     qg = q.reshape(b, sq, hkv, g, d).astype(jnp.float32) * scale
-    og = out.reshape(b, sq, hkv, g, d).astype(jnp.float32)
-    dog = dout.reshape(b, sq, hkv, g, d).astype(jnp.float32)
+    og = out.reshape(b, sq, hkv, g, dv).astype(jnp.float32)
+    dog = dout.reshape(b, sq, hkv, g, dv).astype(jnp.float32)
     # D_i = rowsum(dout ∘ out)  (B, S, hkv, g)
     delta = jnp.sum(og * dog, axis=-1)
 
     dq = jnp.zeros((b, sq, hkv, g, d), jnp.float32)
     dk = jnp.zeros((b, sk, hkv, d), jnp.float32)
-    dv = jnp.zeros((b, sk, hkv, d), jnp.float32)
+    dval = jnp.zeros((b, sk, hkv, dv), jnp.float32)
 
     nq = sq // q_block
     for cj in range(sk // kv_chunk):
@@ -233,7 +239,7 @@ def _bwd_rule(causal, window, q_block, kv_chunk, q_offset, res, dout):
             return (dkj, dvj), dq_blk
 
         dk0 = jnp.zeros((b, kv_chunk, hkv, d), jnp.float32)
-        dv0 = jnp.zeros((b, kv_chunk, hkv, d), jnp.float32)
+        dv0 = jnp.zeros((b, kv_chunk, hkv, dv), jnp.float32)
         (dkj, dvj), dq_blocks = jax.lax.scan(step, (dk0, dv0), idxs)
         # dq_blocks: (nqj, B, q_block, hkv, g, d) -> add into dq
         nqj = qb1 - qb0
@@ -253,15 +259,15 @@ def _bwd_rule(causal, window, q_block, kv_chunk, q_offset, res, dout):
             k_lo,
             axis=1,
         )
-        dv = jax.lax.dynamic_update_slice_in_dim(
-            dv,
-            jax.lax.dynamic_slice_in_dim(dv, k_lo, kv_chunk, 1) + dvj,
+        dval = jax.lax.dynamic_update_slice_in_dim(
+            dval,
+            jax.lax.dynamic_slice_in_dim(dval, k_lo, kv_chunk, 1) + dvj,
             k_lo,
             axis=1,
         )
 
     dq = (dq * scale).reshape(b, sq, h, d).astype(q.dtype)
-    return dq, dk.astype(k.dtype), dv.astype(v.dtype)
+    return dq, dk.astype(k.dtype), dval.astype(v.dtype)
 
 
 flash_attention_jnp.defvjp(_fwd_rule, _bwd_rule)

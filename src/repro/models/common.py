@@ -52,6 +52,19 @@ class ModelConfig:
     num_experts: int = 0
     num_experts_per_tok: int = 0
     capacity_factor: float = 1.25
+    norm_topk_prob: bool = True      # renormalise the top-k gate weights to sum 1
+    moe_d_ff: int = 0                # routed expert width (0 => d_ff)
+    num_shared_experts: int = 0      # always-on experts, one MLP of that many widths
+    first_k_dense: int = 0           # leading layers with a dense MLP of width d_ff
+    experts_held: int = 0            # experts [0, experts_held) on this chip (0 => all)
+    # latent attention (MLA, DeepSeek-V2)
+    kv_lora_rank: int = 0            # 0 => no MLA
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN rope scaling: (factor, original_max_position_embeddings,
+    # beta_fast, beta_slow, mscale, mscale_all_dim); () => plain rope
+    rope_yarn: tuple = ()
     # SSM (mamba2)
     ssm_state: int = 128
     ssm_head_dim: int = 64

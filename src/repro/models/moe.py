@@ -14,9 +14,16 @@ dependency) and GSPMD can shard the expert matmuls:
 No sorts and no O(N·E·C) one-hot einsums: positions-in-expert come from a
 per-group cumsum, gather/scatter move the tokens.  Tokens beyond capacity
 are dropped (Switch/GShard semantics; capacity_factor controls the rate).
+
+``moe_dropless`` is the other layer: told which experts this chip holds,
+it routes every token over all of them and computes its own experts' part
+with no capacity, by a grouped matmul over the token-slots sorted by
+expert (DeepSeek-V2 under expert parallelism, frozen experts).
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -38,6 +45,16 @@ def init_moe_params(key, cfg: ModelConfig) -> dict:
 
 def _shard(rules, x, kind):
     return rules.constrain(x, kind) if rules is not None else x
+
+
+def topk_gates(probs: jnp.ndarray, cfg: ModelConfig) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The top-k experts of each token and their gate weights: renormalised
+    to sum 1 (``cfg.norm_topk_prob``), else the router's probabilities as
+    they are (DeepSeek-V2-Lite, whose ``routed_scaling_factor`` is 1)."""
+    gate_vals, gate_idx = jax.lax.top_k(probs, cfg.num_experts_per_tok)
+    if cfg.norm_topk_prob:
+        return gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True), gate_idx
+    return gate_vals, gate_idx
 
 
 def moe_ffn(
@@ -75,8 +92,7 @@ def _moe_ffn_gspmd(
         "bsd,de->bse", x.astype(jnp.float32), params["router"].astype(jnp.float32)
     )
     probs = jax.nn.softmax(router_logits, axis=-1)            # (B, S, E)
-    gate_vals, gate_idx = jax.lax.top_k(probs, k)              # (B, S, k)
-    gate_vals = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
+    gate_vals, gate_idx = topk_gates(probs, cfg)               # (B, S, k)
 
     # --- per-group dispatch ------------------------------------------------
     expert_of = gate_idx.reshape(b, s * k)                     # (B, S·k)
@@ -164,8 +180,7 @@ def _moe_core_local(
         params_local["router"].astype(jnp.float32),
     )
     probs = jax.nn.softmax(router_logits, axis=-1)
-    gate_vals, gate_idx = jax.lax.top_k(probs, k)
-    gate_vals = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
+    gate_vals, gate_idx = topk_gates(probs, cfg)
 
     expert_of = gate_idx.reshape(b, s * k)
     local_of = expert_of - lo
@@ -262,3 +277,167 @@ def moe_ffn_sharded(
         check_vma=False,
     )(x, params["router"], params["w_gate"], params["w_up"], params["w_down"])
     return out, aux
+
+
+# ---------------------------------------------------------------------------
+# Dropless expert layer over the experts one chip holds
+# ---------------------------------------------------------------------------
+#
+# Expert parallelism without a capacity: the chip holds experts
+# [lo, lo + e_local) of cfg.num_experts, routes every token over all of
+# them and computes its own experts' part of the result.  The T·k
+# token-slots (token, choice) are sorted by held expert, the slots of absent
+# experts last.  The sorted rows are then taken ``rows_per_block`` at a
+# time, up to the last held slot: each block gathers its tokens, runs a
+# grouped matmul (each held expert on its rows of the block) and adds its
+# rows, times their gate weights, into the output.  Every held slot falls in
+# some block, so no routing, however uneven, drops one; the blocks past the
+# last held slot are skipped.  The backward pass walks the same blocks and
+# recomputes each block's hidden activations instead of keeping them: the
+# expert weights are frozen, so it needs the inputs' and the gate weights'
+# cotangents only.  The blocks count the held rows they run through the
+# grouped matmuls; a held slot that no block ran shows as dropped.
+
+
+# The grouped matmuls' device scope; the gossip-FL trainer's trace reads it
+# as a stage of its own, inside the layer's ``fl.moe`` (``models/mla.py``).
+EXPERT_SCOPE = "fl.moe.experts"
+GMM_TILING = (512, 512, 128)      # megablox (rows, contraction, output) tile
+
+
+def grouped_matmul(lhs: jnp.ndarray, rhs: jnp.ndarray, group_sizes: jnp.ndarray,
+                   transpose_rhs: bool = False) -> jnp.ndarray:
+    """Rows of ``lhs`` (M, K) in consecutive groups times ``rhs[g]`` (G, K, N),
+    or its transpose (G, N, K); rows past the last group come out zero.
+    On an accelerator the megablox Pallas kernel, which visits only the row
+    tiles the groups cover and leaves the other rows of its output
+    unwritten (they are zeroed here); on the CPU ``lax.ragged_dot``.  Not
+    differentiable: ``moe_dropless`` writes its backward pass."""
+    from repro.kernels.ops import interpret_mode
+
+    if interpret_mode():
+        return jax.lax.ragged_dot(
+            lhs, jnp.swapaxes(rhs, 1, 2) if transpose_rhs else rhs, group_sizes)
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    out = gmm(lhs, rhs, group_sizes, lhs.dtype, GMM_TILING, transpose_rhs=transpose_rhs)
+    row = jax.lax.broadcasted_iota(jnp.int32, (out.shape[0], 1), 0)
+    return jnp.where(row < jnp.sum(group_sizes), out, 0)
+
+
+def _block_sizes(starts, ends, r0, rows):
+    """Each group's rows within sorted rows [r0, r0 + rows)."""
+    return jnp.clip(ends, r0, r0 + rows) - jnp.clip(starts, r0, r0 + rows)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _expert_blocks(x, weight, token, starts, ends, w, rows):
+    """Σ over sorted rows r < ends[-1] of weight[r] · expert(x[token[r]]),
+    the rows ``rows`` at a time; -> ((T, D) float32, the number of rows the
+    blocks ran through the grouped matmuls)."""
+    out, _ = _expert_blocks_fwd(x, weight, token, starts, ends, w, rows)
+    return out
+
+
+def _block_forward(x, token, starts, ends, w, r0, rows):
+    sizes = _block_sizes(starts, ends, r0, rows)
+    tok = jax.lax.dynamic_slice_in_dim(token, r0, rows)
+    xs = jnp.take(x, tok, axis=0)
+    with jax.named_scope(EXPERT_SCOPE):
+        g = grouped_matmul(xs, w["w_gate"], sizes)
+        u = grouped_matmul(xs, w["w_up"], sizes)
+        ys = grouped_matmul(swiglu(g, u), w["w_down"], sizes)
+    return sizes, tok, g, u, ys
+
+
+def _expert_blocks_fwd(x, weight, token, starts, ends, w, rows):
+    t, d = x.shape
+    blocks = token.shape[0] // rows
+
+    def add_block(carry, b):
+        r0 = b * rows
+
+        def add(carry):
+            out, ran = carry
+            sizes, tok, _, _, ys = _block_forward(x, token, starts, ends, w, r0, rows)
+            wb = jax.lax.dynamic_slice_in_dim(weight, r0, rows)
+            return out.at[tok].add(ys.astype(jnp.float32) * wb[:, None]), ran + jnp.sum(sizes)
+
+        return jax.lax.cond(r0 < ends[-1], add, lambda carry: carry, carry), None
+
+    out, _ = jax.lax.scan(add_block, (jnp.zeros((t, d), jnp.float32), jnp.int32(0)),
+                          jnp.arange(blocks, dtype=jnp.int32))
+    return out, (x, weight, token, starts, ends, w)
+
+
+def _expert_blocks_bwd(rows, res, cot):
+    x, weight, token, starts, ends, w = res
+    dout = cot[0]
+    blocks = token.shape[0] // rows
+
+    def grad_block(dx, b):
+        r0 = b * rows
+
+        def grad(dx):
+            sizes, tok, g, u, ys = _block_forward(x, token, starts, ends, w, r0, rows)
+            wb = jax.lax.dynamic_slice_in_dim(weight, r0, rows)
+            dyw = jnp.take(dout, tok, axis=0)                      # (rows, D) f32
+            dwb = jnp.sum(dyw * ys.astype(jnp.float32), axis=-1)
+            dys = (dyw * wb[:, None]).astype(ys.dtype)
+            with jax.named_scope(EXPERT_SCOPE):
+                dh = grouped_matmul(dys, w["w_down"], sizes, transpose_rhs=True)
+                _, swiglu_vjp = jax.vjp(swiglu, g, u)
+                dg, du = swiglu_vjp(dh)
+                dxs = (grouped_matmul(dg, w["w_gate"], sizes, transpose_rhs=True)
+                       + grouped_matmul(du, w["w_up"], sizes, transpose_rhs=True))
+            return dx.at[tok].add(dxs.astype(jnp.float32)), dwb
+
+        return jax.lax.cond(r0 < ends[-1], grad,
+                            lambda dx: (dx, jnp.zeros(rows, jnp.float32)), dx)
+
+    dx, dw = jax.lax.scan(grad_block, jnp.zeros(x.shape, jnp.float32),
+                          jnp.arange(blocks, dtype=jnp.int32))
+    return (dx.astype(x.dtype), dw.reshape(-1).astype(weight.dtype), None, None, None,
+            jax.tree.map(jnp.zeros_like, w))
+
+
+_expert_blocks.defvjp(_expert_blocks_fwd, _expert_blocks_bwd)
+
+
+def moe_dropless(
+    params: dict, x: jnp.ndarray, cfg: ModelConfig, lo: int, e_local: int,
+    rows_per_block: int = 16384,
+) -> tuple[jnp.ndarray, dict]:
+    """This chip's part of the expert layer for tokens ``x`` (T, D): routing
+    over all ``cfg.num_experts`` (float32 router, softmax, ``topk_gates``),
+    then experts [lo, lo + e_local) (``params["w_gate"]`` etc. hold just
+    those, (e_local, ·, ·), frozen) on every token-slot routed to them.
+
+    Returns ``(out (T, D), stats)``: ``expert_slots`` (e_local,) the slots
+    routed to each held expert, and ``dropped_slots`` the held slots that
+    the blocks did not run through the grouped matmuls (0 when every block
+    that holds one runs).
+    """
+    t, d = x.shape
+    k = cfg.num_experts_per_tok
+    logits = jnp.dot(x.astype(jnp.float32), params["router"].astype(jnp.float32))
+    gate_vals, gate_idx = topk_gates(jax.nn.softmax(logits, axis=-1), cfg)
+
+    local = gate_idx.reshape(t * k) - lo
+    held = (local >= 0) & (local < e_local)
+    key = jnp.where(held, local, e_local)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)     # sorted row -> slot
+    group_sizes = jnp.zeros(e_local + 1, jnp.int32).at[key].add(1)[:e_local]
+    ends = jnp.cumsum(group_sizes)
+    weight = jnp.where(held, gate_vals.reshape(t * k), 0.0)[order]
+
+    n = t * k
+    rows = min(rows_per_block, n)
+    pad = -n % rows
+    token = jnp.pad(order // k, (0, pad))
+    weight = jnp.pad(weight, (0, pad))
+    experts = {name: params[name] for name in ("w_gate", "w_up", "w_down")}
+    out, ran = _expert_blocks(x, weight, token, ends - group_sizes, ends, experts, rows)
+    stats = {"expert_slots": group_sizes,
+             "dropped_slots": jnp.sum(held, dtype=jnp.int32) - ran}
+    return out.astype(x.dtype), stats

@@ -47,6 +47,9 @@ CNN_PARAMS = 552_714
 # (benchmarks/fig6_gossip_fl.py), which chip_smoke --chips 4 trains
 MLP_PARAMS = 8 * 8 * 16 + 16 + 16 * 10 + 10
 USERS = (64, 100)
+# LoRA adapters (rank 16 on q, kv_a, kv_b, o) of DeepSeek-V2-Lite's 5 layers
+# in the benchmark's LoRA cell
+LORA_PARAMS = 1_315_840
 
 
 @pytest.fixture(scope="module")
@@ -155,9 +158,10 @@ def test_topk_select_and_mask_compile_without_a_sort(compile_for_chip, users,
 
 @pytest.mark.parametrize(
     "users,length",
-    # the CNN at 64 and 100 users, and chip_smoke --chips 4's single-device
-    # reference: 1024 users of the MLP, whose mixing matrix is tiled
-    [(64, CNN_PARAMS), (100, CNN_PARAMS), (1024, MLP_PARAMS)],
+    # the CNN at 64 and 100 users, chip_smoke --chips 4's single-device
+    # reference: 1024 users of the MLP, whose mixing matrix is tiled, and
+    # the LoRA cell's 16 users of DeepSeek-V2-Lite adapters
+    [(64, CNN_PARAMS), (100, CNN_PARAMS), (1024, MLP_PARAMS), (16, LORA_PARAMS)],
 )
 def test_gossip_mix_all_compiles(compile_for_chip, users, length):
     bl = mix_block_len(length, users, users)
@@ -184,3 +188,26 @@ def test_gossip_mix_block_compiles(compile_for_chip, block, halo, length):
         ((block, padded), F32), ((block, block), F32),
         ((halo, padded), F32), ((block, halo), F32),
     )
+
+
+def test_dropless_expert_layer_compiles_with_the_grouped_matmul_kernel(
+        compile_for_chip, monkeypatch):
+    """DeepSeek-V2-Lite's expert layer, the 8 experts one chip of 8 holds,
+    forward and backward, on 4,096 tokens (two blocks of sorted rows)."""
+    import repro.kernels.ops as kops
+    from repro.configs import get_config
+    from repro.models import moe
+
+    monkeypatch.setattr(kops, "interpret_mode", lambda: False)
+    cfg = get_config("deepseek-v2-lite").replace(experts_held=8)
+    d, fe, t = cfg.d_model, cfg.moe_d_ff, 4096
+
+    def layer(x, router, wg, wu, wd, cot):
+        p = {"router": router, "w_gate": wg, "w_up": wu, "w_down": wd}
+        out, vjp = jax.vjp(lambda x: moe.moe_dropless(p, x, cfg, 0, 8)[0], x)
+        return out, vjp(cot)[0]
+
+    bf16 = jnp.bfloat16
+    compile_for_chip(layer, ((t, d), bf16), ((d, cfg.num_experts), bf16),
+                     ((8, d, fe), bf16), ((8, d, fe), bf16), ((8, fe, d), bf16),
+                     ((t, d), bf16))
